@@ -50,6 +50,12 @@ print(json.dumps(dict(n=n, wall=dt, per_dev_evals=per_dev,
 
 
 def run(fast=True):
+    import jax
+    if jax.default_backend() == "tpu":
+        # Its children force host CPU devices, and this process holds the
+        # chip: their rows would say nothing about the chip.
+        raise RuntimeError("table8 runs forced host CPU devices only; on "
+                           "a TPU run the sharded path on the chip itself")
     devs = [1, 2, 4, 8]
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(

@@ -260,14 +260,16 @@ def main() -> None:
     }
     common.reset_rows()
     print("name,us_per_call,derived")
+    failed = []
     for key, mod in suites.items():
         if only and key not in only:
             continue
         t0 = time.time()
         try:
             mod.run(fast=fast)
-        except Exception as e:  # keep the harness alive per-suite
+        except Exception as e:  # run the other suites, then exit non-zero
             print(f"{key}/ERROR,0,{type(e).__name__}: {e}", file=sys.stdout)
+            failed.append(key)
         print(f"{key}/_suite_wall,{(time.time()-t0)*1e6:.0f},",
               file=sys.stdout)
 
@@ -336,6 +338,10 @@ def main() -> None:
         print(f"# abs gate OK ({checked} rows checked vs prior, "
               f"{skipped} skipped: generic-cpu or no prior)",
               file=sys.stderr)
+
+    if failed:
+        print(f"# suites failed: {', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.integrands import Integrand
+from repro.core.integrands import Integrand, cumsum_cols
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,7 +89,7 @@ def make_asian_family(strikes, n_steps: int = 8, s0: float = 100.0,
         eps = 1e-6 if x.dtype == jnp.float32 else 1e-12
         xc = jnp.clip(x, eps, 1.0 - eps)
         z = jax.scipy.special.erfinv(2.0 * xc - 1.0) * math.sqrt(2.0)
-        logpath = jnp.cumsum(drift + vol * z, axis=-1)
+        logpath = cumsum_cols(drift + vol * z)
         if geometric:
             avg = s0 * jnp.exp(jnp.mean(logpath, axis=-1))
         else:
@@ -136,7 +136,7 @@ def make_asian_greeks_family(strikes, sigmas=None, n_steps: int = 8,
         eps = 1e-6 if x.dtype == jnp.float32 else 1e-12
         xc = jnp.clip(x, eps, 1.0 - eps)
         z = jax.scipy.special.erfinv(2.0 * xc - 1.0) * math.sqrt(2.0)
-        logpath = jnp.cumsum(drift + vol * z, axis=-1)
+        logpath = cumsum_cols(drift + vol * z)
         avg = s0 * jnp.exp(jnp.mean(logpath, axis=-1))
         return math.exp(-r * t_mat) * jnp.maximum(avg - strike, 0.0)
 

@@ -34,6 +34,27 @@ def _unit(name, dim, fn, target):
     return Integrand(name, dim, fn, (0.0,) * dim, (1.0,) * dim, target)
 
 
+# Mosaic (the TPU kernel compiler) lowers no ``reduce_prod`` and no
+# ``cumsum``, and every integrand here is traced into the fill kernel as well
+# as into the ``ref`` oracle.  Both scans therefore run unrolled over the
+# static ``d`` columns, in the same left-to-right order on every backend.
+
+def prod_cols(v):
+    """Product over the last axis of ``v (n, d)`` -> ``(n,)``."""
+    out = v[:, 0:1]
+    for k in range(1, v.shape[-1]):
+        out = out * v[:, k:k + 1]
+    return out[:, 0]
+
+
+def cumsum_cols(v):
+    """Running sum over the last axis of ``v (n, d)`` -> ``(n, d)``."""
+    cols = [v[:, 0:1]]
+    for k in range(1, v.shape[-1]):
+        cols.append(cols[-1] + v[:, k:k + 1])
+    return jnp.concatenate(cols, axis=-1)
+
+
 # --- Table 3 -----------------------------------------------------------------
 
 def make_sine_exp():
@@ -49,7 +70,7 @@ def make_linear(dim=10):
 
 def make_cosine(dim=10):
     # (3) f = prod cos(x_i). Integral = sin(1)^d.
-    return _unit("cosine", dim, lambda x: jnp.prod(jnp.cos(x), axis=-1),
+    return _unit("cosine", dim, lambda x: prod_cols(jnp.cos(x)),
                  math.sin(1.0) ** dim)
 
 
@@ -64,7 +85,7 @@ def make_exponential(dim=10):
 def make_roos_arnold(dim=10):
     # (5) f = prod |4 x_i - 2|. Integral = 1.
     return _unit("roos_arnold", dim,
-                 lambda x: jnp.prod(jnp.abs(4.0 * x - 2.0), axis=-1), 1.0)
+                 lambda x: prod_cols(jnp.abs(4.0 * x - 2.0)), 1.0)
 
 
 def make_morokoff_caflisch(dim=8):
@@ -134,7 +155,7 @@ def make_asian_option(n_steps=16, s0=100.0, strike=100.0, r=0.1, sigma=0.2,
         xc = jnp.clip(x, eps, 1.0 - eps)
         z = jax.scipy.special.erfinv(2.0 * xc - 1.0) * math.sqrt(2.0)
         logret = drift + vol * z                       # (n, d) per-step log-returns
-        logpath = jnp.cumsum(logret, axis=-1)          # (n, d) log S_k/S0
+        logpath = cumsum_cols(logret)                  # (n, d) log S_k/S0
         if geometric:
             avg = s0 * jnp.exp(jnp.mean(logpath, axis=-1))
         else:

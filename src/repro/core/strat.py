@@ -79,5 +79,8 @@ def adapt_nh(d_h: jax.Array, beta, neval: int, n_min: int = 2) -> jax.Array:
     d_h = jnp.maximum(d_h, 0.0)
     p = d_h ** beta
     tot = jnp.sum(p)
-    p = jnp.where(tot > 0, p / jnp.maximum(tot, 1e-30), 1.0 / d_h.shape[0])
+    # A total at or under the clamp carries no signal: normalizing by the
+    # clamp instead of the total would drop sum(n_h) below neval - n_cubes.
+    p = jnp.where(tot > 1e-30, p / jnp.maximum(tot, 1e-30),
+                  1.0 / d_h.shape[0])
     return jnp.maximum(jnp.floor(neval * p), n_min).astype(jnp.int32)

@@ -138,6 +138,14 @@ def make_plan(workload, cfg: core.VegasConfig | None = None,
         raise PlanError(
             f"backend {spec.name!r} supports precision pairs [{pairs}], got "
             f"{dtype_name}->{accum_name}")
+    if accum_name != dtype_name and spec.family == "tpu" \
+            and "interpret" in spec.knobs:
+        from repro import kernels
+        if not kernels.resolve_interpret(execution.interpret, spec.family):
+            raise PlanError(
+                f"backend {spec.name!r} compiled for TPU cannot accumulate "
+                f"in {accum_name}: Mosaic lowers no float64 (drop the "
+                f"widened PrecisionPolicy, or run interpret=True)")
     import jax.dtypes as _jdtypes
     if accum_name != dtype_name and \
             _jdtypes.canonicalize_dtype(accum_name).name != accum_name:

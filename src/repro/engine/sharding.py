@@ -22,11 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6: shard_map graduated out of experimental
-    from jax import shard_map as shard_map
-except ImportError:  # jax <= 0.5.x
-    from jax.experimental.shard_map import shard_map
-
 from . import backends as backends_mod
 
 REPLICATED = P()
@@ -100,14 +95,14 @@ def make_local_fill(rcfg, mesh, axis_names, *, backend: str | None = None):
 def replicated_shard_map(body, mesh, n_args: int):
     """Wrap ``body`` in a replicated-in / replicated-out ``shard_map``.
 
-    ``check_rep=False``: ``pallas_call`` has no replication rule under
+    ``check_vma=False``: ``pallas_call`` has no replication rule under
     shard_map, and the psum inside the body already replicates every output
     explicitly (each device computes the identical O(KB) adaptation state;
     only the fill is divided).
     """
-    return shard_map(body, mesh=mesh,
-                     in_specs=(REPLICATED,) * n_args,
-                     out_specs=REPLICATED, check_rep=False)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(REPLICATED,) * n_args,
+                         out_specs=REPLICATED, check_vma=False)
 
 
 def make_stop_sync(axis_names):

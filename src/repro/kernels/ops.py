@@ -55,29 +55,90 @@ def key_bits(key) -> jax.Array:
     return key
 
 
+#: Row count the integrand is traced at to size its intermediates: odd, so
+#: no other axis of a realistic integrand matches it by accident.
+_PROBE_ROWS = 257
+_SUBLANE = 8
+#: VMEM bytes per element of a one-hot operand: the f32 one-hot plus the
+#: bf16 pieces a full-precision (HIGHEST) MXU contraction splits it into.
+#: Mosaic's scoped allocation on a TPU v5e comes to 16-17 bytes per one-hot
+#: element across d = 2..16 at ninc 1024.
+_ONEHOT_BYTES = 16
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _row_bytes(aval, rows: int) -> int:
+    """VMEM bytes per evaluation row of one intermediate whose leading axis
+    is the tile's rows, laid out as Mosaic tiles f32: the minor axis padded
+    to LANE, the second-minor to 8 sublanes.  0 for anything else."""
+    shape = getattr(aval, "shape", ())
+    if not shape or shape[0] != rows:
+        return 0
+    itemsize = aval.dtype.itemsize
+    if len(shape) == 1:                  # (rows,): rows ride the lanes
+        return itemsize
+    if len(shape) == 2:                  # (rows, k): rows ride the sublanes
+        return _round_up(shape[1], vk.LANE) * itemsize
+    inner = 1
+    for n in shape[1:-2]:
+        inner *= n
+    return (inner * _round_up(shape[-2], _SUBLANE)
+            * _round_up(shape[-1], vk.LANE) * itemsize)
+
+
+def _jaxpr_row_bytes(jaxpr, rows: int) -> int:
+    best = 0
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            best = max(best, _row_bytes(v.aval, rows))
+        for p in eqn.params.values():
+            sub = getattr(p, "jaxpr", p)          # ClosedJaxpr -> Jaxpr
+            if hasattr(sub, "eqns"):
+                best = max(best, _jaxpr_row_bytes(sub, rows))
+    return best
+
+
+def eval_row_bytes(integrand, d: int, dtype=jnp.float32) -> int:
+    """Per-row VMEM bytes of the integrand's largest intermediate, read off
+    its jaxpr (ridge's ``(tile, 1000, d)`` peak distances: 512 KB a row).
+    Mosaic keeps the integrand's intermediates in VMEM next to the fill's
+    own scratch, so the tile budget must charge them.  The largest one
+    alone is the charge: Mosaic reuses buffers between the others (it
+    allocates ~250 KB a row for ridge, under this 512 KB bound)."""
+    closed = jax.make_jaxpr(lambda xx: integrand(xx))(
+        jax.ShapeDtypeStruct((_PROBE_ROWS, d), dtype))
+    return _jaxpr_row_bytes(closed.jaxpr, _PROBE_ROWS)
+
+
 def tile_footprint_bytes(tile: int, d: int, ninc: int, n_cubes: int, *,
-                         accum_itemsize: int = 4) -> int:
+                         accum_itemsize: int = 4, row_bytes: int = 0) -> int:
     """VMEM footprint of one kernel tile under the DESIGN.md §7/§15 budget
-    math: the d pass-1 one-hots stay live for pass-2 reuse (d * tile * ninc,
-    f32 — products feed the MXU in the sample dtype), the cube-window
-    one-hot adds tile * span, the transform scratch ~8 copies of (tile, d),
-    plus the grid-resident state — the f32 map tables (2 * d * ninc) and the
-    ACCUMULATORS at ``accum_itemsize`` bytes apiece: the (d, ninc) ms/mc
-    histogram pair and the two (rows, LANE) cube-moment tiles (~2.1 MB f32 /
-    ~4.2 MB f64 at the max_cubes = 2^18 cap).  Widened f64 accumulation
-    therefore shrinks the budget available to per-tile scratch — the §15
-    VMEM tradeoff `valid_tiles` prices."""
+    math: the d pass-1 one-hots stay live for pass-2 reuse (d * tile * ninc
+    elements at ``_ONEHOT_BYTES`` — products feed the MXU in the sample
+    dtype, at full f32 precision), the cube-window one-hot adds tile * span
+    more, the transform scratch ~8 f32 copies of (tile, d),
+    the integrand's own intermediates (``row_bytes`` a row, see
+    :func:`eval_row_bytes`), plus the grid-resident state — the f32 map
+    tables (2 * d * ninc) and the ACCUMULATORS at ``accum_itemsize`` bytes
+    apiece: the (d, ninc) ms/mc histogram pair and the two (rows, LANE)
+    cube-moment tiles (~2.1 MB f32 / ~4.2 MB f64 at the max_cubes = 2^18
+    cap).  Widened f64 accumulation therefore shrinks the budget available
+    to per-tile scratch — the §15 VMEM tradeoff `valid_tiles` prices."""
     span = vk.span_for_tile(tile)
     resident = (4 * 2 * d * ninc
                 + accum_itemsize * (2 * d * ninc
                                     + 2 * vk.padded_cube_rows(n_cubes, tile)
                                     * vk.LANE))
-    return 4 * (d * tile * ninc + tile * span + 8 * tile * d) + resident
+    return (_ONEHOT_BYTES * (d * tile * ninc + tile * span)
+            + 4 * 8 * tile * d + tile * row_bytes + resident)
 
 
 def valid_tiles(chunk: int, d: int, ninc: int, n_cubes: int, *,
-                vmem_budget: int = 8 << 20,
-                max_tile: int = 1024, accum_itemsize: int = 4) -> list[int]:
+                vmem_budget: int = 8 << 20, max_tile: int = 1024,
+                accum_itemsize: int = 4, row_bytes: int = 0) -> list[int]:
     """Every tile the kernel accepts for this shape, ascending: divisors of
     ``chunk`` whose :func:`tile_footprint_bytes` fits the VMEM budget.
 
@@ -85,30 +146,35 @@ def valid_tiles(chunk: int, d: int, ninc: int, n_cubes: int, *,
     takes the largest entry) and the plan autotuner (`engine.autotune`, which
     scores entries with the measured cost model) — so the autotuner can never
     choose a tile the kernel would reject.  ``accum_itemsize`` prices the
-    grid-resident accumulators (8 under an f64 PrecisionPolicy, §15).
+    grid-resident accumulators (8 under an f64 PrecisionPolicy, §15);
+    ``row_bytes`` the integrand's intermediates (:func:`eval_row_bytes`).
     """
     return [t for t in range(1, min(chunk, max_tile) + 1)
             if chunk % t == 0
             and tile_footprint_bytes(t, d, ninc, n_cubes,
-                                     accum_itemsize=accum_itemsize)
+                                     accum_itemsize=accum_itemsize,
+                                     row_bytes=row_bytes)
             <= vmem_budget]
 
 
 def autotune_tile(chunk: int, d: int, ninc: int, n_cubes: int, *,
                   vmem_budget: int = 8 << 20, max_tile: int = 1024,
-                  accum_itemsize: int = 4) -> int:
+                  accum_itemsize: int = 4, row_bytes: int = 0) -> int:
     """Largest tile that divides ``chunk`` and fits the VMEM budget (the
     static default when no measured cost table picks one)."""
     tiles = valid_tiles(chunk, d, ninc, n_cubes, vmem_budget=vmem_budget,
-                        max_tile=max_tile, accum_itemsize=accum_itemsize)
+                        max_tile=max_tile, accum_itemsize=accum_itemsize,
+                        row_bytes=row_bytes)
     return tiles[-1] if tiles else 1
 
 
 def _pick_tile(tile: int | None, chunk: int, d: int, ninc: int,
-               n_cubes: int, accum_itemsize: int = 4) -> int:
+               n_cubes: int, accum_itemsize: int = 4,
+               row_bytes: int = 0) -> int:
     if tile is None:
         tile = autotune_tile(chunk, d, ninc, n_cubes,
-                             accum_itemsize=accum_itemsize)
+                             accum_itemsize=accum_itemsize,
+                             row_bytes=row_bytes)
     else:
         tile = min(tile, chunk)
         if chunk % tile != 0:
@@ -166,7 +232,9 @@ def fill(edges, n_h, key, integrand, *, nstrat: int, n_cap: int, chunk: int,
     if n_chunks is None:
         assert n_cap % chunk == 0, (n_cap, chunk)
         n_chunks = n_cap // chunk
-    tile = _pick_tile(tile, chunk, d, ninc, n_cubes, accum.itemsize)
+    tile = _pick_tile(tile, chunk, d, ninc, n_cubes, accum.itemsize,
+                      eval_row_bytes(integrand, d, dtype) if tile is None
+                      else 0)
     if fused_cubes and dtype != jnp.float32:
         raise ValueError(
             f"fused_cubes=True is f32-only samples (the in-kernel RNG "

@@ -47,6 +47,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _TINY = 1e-30
+#: Every f32 contraction in these kernels is a one-hot gather or a one-hot
+#: histogram, and must come back exact.  Mosaic's default f32 matmul on
+#: the MXU rounds its operands to bf16 (a gather off by ~3.5e-3 relative on
+#: a TPU v5e); HIGHEST runs it at full f32.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _fill_kernel(u_ref, cube_ref, edges_ref, widths_ref, *rest,
@@ -76,10 +81,10 @@ def _fill_kernel(u_ref, cube_ref, edges_ref, widths_ref, *rest,
         oh = (iy_k == lanes).astype(dtype)                      # (tile, ninc)
         e_lo = jax.lax.dot_general(
             oh, edges_ref[k:k + 1, :], (((1,), (1,)), ((), ())),
-            preferred_element_type=dtype)                       # (tile, 1)
+            precision=_EXACT, preferred_element_type=dtype)  # (tile, 1)
         dx = jax.lax.dot_general(
             oh, widths_ref[k:k + 1, :], (((1,), (1,)), ((), ())),
-            preferred_element_type=dtype)                       # (tile, 1)
+            precision=_EXACT, preferred_element_type=dtype)  # (tile, 1)
         x_cols.append(e_lo + frac * dx)
         iy_cols.append(iy_k)
         logjac = logjac + jnp.log(jnp.maximum(ninc * dx, _TINY))
@@ -106,10 +111,10 @@ def _fill_kernel(u_ref, cube_ref, edges_ref, widths_ref, *rest,
         oh = (iy_cols[k] == lanes).astype(dtype)                # (tile, ninc)
         ms_k = jax.lax.dot_general(
             w2, oh, (((0,), (0,)), ((), ())),
-            preferred_element_type=dtype)                       # (1, ninc)
+            precision=_EXACT, preferred_element_type=dtype)  # (1, ninc)
         mc_k = jax.lax.dot_general(
             cnt, oh, (((0,), (0,)), ((), ())),
-            preferred_element_type=dtype)                       # (1, ninc)
+            precision=_EXACT, preferred_element_type=dtype)  # (1, ninc)
         ms_ref[k:k + 1, :] += ms_k
         mc_ref[k:k + 1, :] += mc_k
 
@@ -333,7 +338,7 @@ def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
         oh = (iy_k == lanes).astype(dtype)                      # (tile, ninc)
         ed = jax.lax.dot_general(
             oh, ew_ref[2 * k:2 * k + 2, :], (((1,), (1,)), ((), ())),
-            preferred_element_type=dtype)                       # (tile, 2)
+            precision=_EXACT, preferred_element_type=dtype)  # (tile, 2)
         e_lo = ed[:, 0:1]
         dx = ed[:, 1:2]
         x_cols.append(e_lo + frac * dx)
@@ -368,7 +373,7 @@ def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
     for k in range(d):
         m_k = jax.lax.dot_general(
             w2cnt, ohs[k], (((0,), (0,)), ((), ())),
-            preferred_element_type=dtype)                       # (2, ninc)
+            precision=_EXACT, preferred_element_type=dtype)  # (2, ninc)
         m_k = m_k.astype(accum)
         ms_ref[k:k + 1, :] += m_k[0:1, :]
         mc_ref[k:k + 1, :] += m_k[1:2, :]
@@ -387,16 +392,17 @@ def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
     both = jnp.concatenate([w, w2], axis=1)                     # (tile, 2)
     parts = jax.lax.dot_general(
         both, ohc, (((0,), (0,)), ((), ())),
-        preferred_element_type=dtype)                           # (2, span)
-    rows_n = span // LANE
+        precision=_EXACT, preferred_element_type=dtype)         # (2, span)
     br = base // LANE
     # Same §15 boundary as the map histogram: the one-hot contraction stays
-    # f32, each tile's (rows_n, LANE) partial is widened once before the
-    # grid-sequential += into the accumulator tiles.
-    p1 = parts[0:1, :].reshape(rows_n, LANE).astype(accum)
-    p2 = parts[1:2, :].reshape(rows_n, LANE).astype(accum)
-    s1_ref[pl.ds(br, rows_n), :] += p1
-    s2_ref[pl.ds(br, rows_n), :] += p2
+    # f32, each tile's partial is widened once before the grid-sequential +=
+    # into the accumulator tiles.  The (1, span) -> (rows, LANE) fold runs
+    # as one lane-aligned slice per accumulator row: Mosaic has no layout
+    # for reshaping a lane-major row vector into sublanes.
+    for r in range(span // LANE):
+        lanes_r = slice(r * LANE, (r + 1) * LANE)
+        s1_ref[pl.ds(br + r, 1), :] += parts[0:1, lanes_r].astype(accum)
+        s2_ref[pl.ds(br + r, 1), :] += parts[1:2, lanes_r].astype(accum)
 
 
 def vegas_fill_fused(key_bits, cube, edges_lo, widths, *, nstrat: int,

@@ -12,7 +12,10 @@ Three idioms (see SNIPPETS.md for their upstream forms):
     for future compiled-GPU rows;
   * **host devices** — :func:`set_host_device_count` forces N host CPU
     devices via ``XLA_FLAGS`` (the multi-device tests' idiom) — it MUST run
-    before jax first initializes its backends.
+    before jax first initializes its backends;
+  * **compile cache** — :func:`use_compile_cache` turns on JAX's persistent
+    compilation cache for the CLIs and ``chip_smoke.py`` (never on
+    ``import repro``).
 
 Everything importing jax does so lazily inside the function, so this module
 can be imported (and ``set_host_device_count`` called) before jax is — the
@@ -24,6 +27,12 @@ from __future__ import annotations
 import os
 import re
 import warnings
+from pathlib import Path
+
+#: Where compiled programs are cached when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed directory of the checkout, so a later run finds them again
+#: (the path is part of the cache key; gitignored).
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 #: The documented GPU launch profile: every XLA flag the compiled-GPU
 #: benchmark rows run under, with the rationale each flag is there for.
@@ -119,11 +128,23 @@ def set_platform(platform: str | None = None, *,
                       RuntimeWarning, stacklevel=2)
         return platform
     import jax
-    try:
-        jax.config.update("jax_platforms", platform)
-    except (AttributeError, ValueError):   # older spelling
-        jax.config.update("jax_platform_name", platform)
+    jax.config.update("jax_platforms", platform)
     return platform
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    A set ``JAX_COMPILATION_CACHE_DIR`` is left to JAX, which reads it
+    itself: nothing is set in code.  Otherwise the cache goes to
+    :data:`CACHE_DIR`.  Call before the first compilation — JAX decides
+    once per process whether a cache is in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    return str(CACHE_DIR)
 
 
 def set_host_device_count(n: int) -> int:

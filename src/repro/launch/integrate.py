@@ -43,7 +43,7 @@ INTEGRANDS = {
 def add_execution_args(ap: argparse.ArgumentParser) -> None:
     """The shared execution-axis flags (integrate + sweep CLIs)."""
     ap.add_argument("--backend",
-                    choices=sorted(available()) + ["auto"], default="ref",
+                    choices=sorted(available()) + ["auto"], default="auto",
                     help="fill backend from the engine registry "
                          "(pallas-fused = P-V3 streaming kernel, pallas-gpu "
                          "= Triton scatter kernel; auto = platform default "
@@ -141,6 +141,7 @@ def main(argv=None):
     add_execution_args(ap)
     args = ap.parse_args(argv)
     env.apply_env_args(args)
+    env.use_compile_cache()
 
     ig = INTEGRANDS[args.integrand]()
     base = PAPER_CONFIGS[args.config]

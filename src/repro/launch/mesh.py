@@ -2,30 +2,20 @@
 
 FUNCTIONS, not module constants: importing this module must never touch jax
 device state (multi-device tests set the host-device-count override before
-any jax initialization).
-
-``make_mesh`` papers over a jax API gap: ``jax.sharding.AxisType`` (and the
-``axis_types=`` kwarg of ``jax.make_mesh``) only exists on newer jax; on
-older versions every mesh axis is implicitly Auto, which is exactly what we
-want, so the kwarg is simply dropped.  All mesh construction in this repo
-(tests, examples, benches) goes through this one shim."""
+any jax initialization).  All mesh construction in this repo (tests,
+examples, benches) goes through :func:`make_mesh`."""
 
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # older jax: all axes behave as Auto
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types across jax versions."""
-    if _AxisType is not None:
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(_AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with every axis Auto (the sharded fill partitions
+    its chunk axis by hand inside ``shard_map``)."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_local_mesh():

@@ -92,6 +92,7 @@ def main(argv=None):
     env.add_env_args(ap)
     args = ap.parse_args(argv)
     env.apply_env_args(args)
+    env.use_compile_cache()
 
     if args.requests:
         requests = _load_requests(args.requests)

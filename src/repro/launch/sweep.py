@@ -48,6 +48,7 @@ def main(argv=None):
     add_execution_args(ap)
     args = ap.parse_args(argv)
     env.apply_env_args(args)
+    env.use_compile_cache()
 
     family = FAMILIES[args.family](args.batch)
     execution = build_execution(args)

@@ -63,7 +63,7 @@ class IntegrationRequest:
     #: compatibility key: requests under different precision policies never
     #: coalesce into one program.
     accum_dtype: str | None = None
-    backend: str = "ref"
+    backend: str = "auto"           # platform default: pallas-fused on TPU
     interpret: bool | None = None
     tile: int | None = None
     family_kwargs: tuple = ()

@@ -287,7 +287,7 @@ class SweepService:
         max_it = rcfg.max_it
         with self._lock:
             unit = self._cost.unit(tickets[0].compat_key, rcfg=rcfg,
-                                   backend=req0.backend,
+                                   backend=rcfg.execution.backend,
                                    interpret=req0.interpret, tile=req0.tile)
         caps, enforced = [], unit is not None
         for t in tickets:

@@ -274,3 +274,32 @@ def test_run_batch_through_engine_matches_serial():
     for b in range(3):
         comb = float(np.hypot(batched.sdev[b], serial[b].sdev))
         assert abs(float(batched.mean[b]) - serial[b].mean) < 3 * comb
+
+
+# --- entry-point defaults and the compile cache -----------------------------
+
+def test_cli_and_requests_default_to_the_platform_backend(monkeypatch):
+    from repro import kernels
+    from repro.launch import env
+    from repro.launch.integrate import main
+    from repro.serve import IntegrationRequest
+    monkeypatch.setattr(env, "use_compile_cache", lambda: None)
+    assert IntegrationRequest(family="gaussian", params=[0.5]).backend == "auto"
+    plan = main(["--integrand", "gaussian", "--neval", "1000", "--plan"])
+    assert plan.backend.name == kernels.backend_default()
+
+
+def test_use_compile_cache_leaves_a_set_directory_to_jax(monkeypatch):
+    import pathlib
+
+    from repro.launch import env
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert env.use_compile_cache() == "/elsewhere/cache" and calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert env.use_compile_cache() == str(env.CACHE_DIR)
+    assert calls == [("jax_compilation_cache_dir", str(env.CACHE_DIR))]
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert env.CACHE_DIR == repo / ".jax_cache"

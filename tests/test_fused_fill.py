@@ -329,3 +329,18 @@ def test_fused_rejects_non_f32():
     with pytest.raises(ValueError, match="f32-only"):
         kops.fill(edges, n_h, jax.random.PRNGKey(0), _ig, nstrat=3,
                   n_cap=512, chunk=512, dtype=jnp.float16, fused_cubes=True)
+
+
+def test_eval_row_bytes_charges_integrand_intermediates():
+    """Ridge's (tile, 1000, d) peak distances cost 1000 x 128 lanes x 4 B a
+    row as Mosaic pads them, and shrink the autotuned tile; a plain
+    (tile, d) integrand costs one padded row."""
+    from repro.core import integrands as igs
+    ridge = igs.make_ridge(dim=4, n_peaks=1000)
+    assert kops.eval_row_bytes(ridge, 4) == 1000 * 128 * 4
+    assert kops.eval_row_bytes(igs.make_gaussian(dim=4), 4) == 128 * 4
+    kw = dict(chunk=16_384, d=4, ninc=1024, n_cubes=22**4)
+    with_rows = kops.autotune_tile(**kw, row_bytes=1000 * 128 * 4)
+    assert with_rows < kops.autotune_tile(**kw)
+    assert kops.tile_footprint_bytes(with_rows, 4, 1024, 22**4,
+                                     row_bytes=1000 * 128 * 4) <= 8 << 20

@@ -119,3 +119,14 @@ def test_importance_only_mode():
                       chunk=8192)
     r = run(ig, cfg, key=jax.random.PRNGKey(2))
     assert abs(r.mean - ig.target) / r.sdev < 5
+
+
+@pytest.mark.parametrize("d", [1, 2, 10, 16])
+def test_unrolled_column_scans_match_jnp(d):
+    """prod_cols/cumsum_cols (what Mosaic can lower) == jnp.prod/cumsum."""
+    x = jax.random.uniform(jax.random.PRNGKey(d), (37, d), minval=0.5,
+                           maxval=1.5)
+    np.testing.assert_allclose(igs.prod_cols(x), jnp.prod(x, axis=-1),
+                               rtol=1e-6)
+    np.testing.assert_allclose(igs.cumsum_cols(x), jnp.cumsum(x, axis=-1),
+                               rtol=1e-6)

@@ -73,6 +73,15 @@ def test_adapt_nh_allocates_to_high_variance():
     assert n_h[2] > 10 * n_h[1]
 
 
+@pytest.mark.parametrize("tiny", [0.0, 1.1754944e-38, 1e-31])
+def test_adapt_nh_signal_free_total_allocates_uniformly(tiny):
+    """A variance total at or under the normalizing clamp still hands out
+    ~neval evaluations (uniformly), not just the per-cube floor."""
+    d_h = jnp.full((4,), tiny / 4, jnp.float32)
+    n_h = np.asarray(strat.adapt_nh(d_h, 1.0, neval=1000))
+    np.testing.assert_array_equal(n_h, [250] * 4)
+
+
 def test_stratified_y_stays_in_cube():
     key = jax.random.PRNGKey(3)
     nstrat, dim, n = 3, 4, 256

@@ -1,0 +1,120 @@
+"""Mosaic compile rehearsals: the fused fill compiled for a described TPU v5e
+(no chip attached) at the sizes the chip runs use — ninc 1024, chunk 16384,
+neval 1e7, ``interpret=False`` so the kernel is lowered by Mosaic, not
+interpreted.  A compile that the chip's compiler would refuse (an unaligned
+reshape, a primitive Pallas cannot lower, a tile over the scoped VMEM limit)
+fails here first.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library at a time, and every test worker
+imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.batch.family import make_gaussian_family
+from repro.core import VegasConfig
+from repro.core import integrands as igs
+from repro.engine import ExecutionConfig, PlanError, PrecisionPolicy, make_plan
+from repro.engine import sharding as sharding_mod
+from repro.kernels import ops as kops
+from repro.launch.integrate import INTEGRANDS
+
+NEVAL = 10_000_000
+NINC = 1024
+CHUNK = 16_384
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it.
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    from jax.experimental import topologies
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _fill_args(rc, sharding, batch=()):
+    return (jax.ShapeDtypeStruct(batch + (rc.dim, rc.ninc + 1), jnp.float32,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct(batch + (rc.n_cubes,), jnp.int32,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct(batch + (2,), jnp.uint32, sharding=sharding))
+
+
+def _compiled_fill(integrand, rc):
+    def fill(edges, n_h, key):
+        return kops.fill(edges, n_h, key, integrand, nstrat=rc.nstrat,
+                         n_cap=rc.n_cap, chunk=rc.chunk, interpret=False)
+    return fill
+
+
+@pytest.mark.parametrize("name", sorted(INTEGRANDS))
+def test_fused_fill_compiles_for_v5e(one_chip, name):
+    ig = INTEGRANDS[name]()
+    rc = VegasConfig(neval=NEVAL, ninc=NINC, chunk=CHUNK).resolve(ig.dim)
+    compiled = jax.jit(_compiled_fill(ig, rc)).lower(
+        *_fill_args(rc, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_vmapped_family_fill_compiles_for_v5e(one_chip):
+    fam = make_gaussian_family(np.linspace(0.2, 0.8, 64))
+    rc = VegasConfig(neval=NEVAL, ninc=NINC, chunk=CHUNK).resolve(fam.dim)
+
+    def fill(params, edges, n_h, key):
+        one = lambda p, e, nh, k: _compiled_fill(fam.bind(p), rc)(e, nh, k)
+        return jax.vmap(one)(params, edges, n_h, key)
+
+    params = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(fill).lower(
+        params, *_fill_args(rc, one_chip, batch=(64,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sharded_fill_compiles_for_v5e_2x2(topo):
+    ig = igs.make_gaussian()
+    mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("data",))
+    cfg = VegasConfig(neval=10 * NEVAL, ninc=NINC, chunk=CHUNK,
+                      execution=ExecutionConfig(backend="pallas-fused",
+                                                interpret=False))
+    rc = cfg.resolve(ig.dim)
+    fill = sharding_mod.make_sharded_fill(mesh, ("data",), rc)
+    compiled = jax.jit(lambda e, nh, k: fill(e, nh, k, ig)).lower(
+        *_fill_args(rc, NamedSharding(mesh, P()))).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert "all-reduce" in hlo
+
+
+def test_widened_accumulation_refused_when_compiled_for_tpu():
+    cfg = VegasConfig(neval=NEVAL, ninc=NINC, chunk=CHUNK)
+    widened = PrecisionPolicy(accum_dtype="float64")
+    for backend in ("pallas", "pallas-fused"):
+        compiled = ExecutionConfig(backend=backend, interpret=False,
+                                   precision=widened)
+        with pytest.raises(PlanError, match="Mosaic lowers no float64") as e:
+            make_plan(igs.make_gaussian(), cfg, compiled)
+        assert "\n" not in str(e.value)
