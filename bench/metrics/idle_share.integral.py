@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of the operations' intervals) / window."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if t["window_s"] <= 0:
+        return None
+    return 1.0 - t["busy_s"] / t["window_s"]
