@@ -20,6 +20,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 from . import map as vmap_
 from . import strat
 
@@ -193,13 +195,14 @@ def estimate_from_cubes(res: FillResult, n_h: jax.Array):
     Returns (I_it, sigma2_it, d_h) with d_h = per-cube sample sigma — the
     allocation signal n_h ∝ d_h^beta ("n_h proportional to sigma_h(Jf)").
     """
-    n_cubes = n_h.shape[0]
-    nh = jnp.maximum(n_h.astype(res.cube_s1.dtype), 1.0)
-    v = 1.0 / n_cubes
-    m = res.cube_s1 / nh
-    q = res.cube_s2 / nh
-    var = jnp.maximum(q - m * m, 0.0)
-    i_it = v * jnp.sum(m)
-    sigma2 = v * v * jnp.sum(var / jnp.maximum(nh - 1.0, 1.0))
-    d_h = jnp.sqrt(var)
-    return i_it, sigma2, d_h
+    with obs.scope("vegas.estimate"):
+        n_cubes = n_h.shape[0]
+        nh = jnp.maximum(n_h.astype(res.cube_s1.dtype), 1.0)
+        v = 1.0 / n_cubes
+        m = res.cube_s1 / nh
+        q = res.cube_s2 / nh
+        var = jnp.maximum(q - m * m, 0.0)
+        i_it = v * jnp.sum(m)
+        sigma2 = v * v * jnp.sum(var / jnp.maximum(nh - 1.0, 1.0))
+        d_h = jnp.sqrt(var)
+        return i_it, sigma2, d_h

@@ -16,6 +16,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.engine.config import LEGACY_EXEC_FIELDS, ExecutionConfig
 
 from . import fill as fill_mod
@@ -329,14 +330,19 @@ def run_loop(state: VegasState, integrand: Integrand, cfg: ResolvedConfig,
     # alongside the state — inspectable mid-loop and re-derivable on resume.
     cap = jnp.asarray(cfg.max_it if it_cap is None else it_cap, jnp.int32)
 
+    def stop_test(s, cap):
+        with obs.scope("vegas.stop"):
+            stats = running_stats(s)
+            return stats, wants_more(s, stats, cap)
+
     def body(carry):
         s, _, cap, _ = carry
         s = iteration_step(s, integrand, cfg, fill_fn)
-        stats = running_stats(s)
-        return s, stats, cap, wants_more(s, stats, cap)
+        stats, cont = stop_test(s, cap)
+        return s, stats, cap, cont
 
-    stats0 = running_stats(state)
-    carry = (state, stats0, cap, wants_more(state, stats0, cap))
+    stats0, cont0 = stop_test(state, cap)
+    carry = (state, stats0, cap, cont0)
     state, _, _, _ = jax.lax.while_loop(lambda c: c[3], body, carry)
     return state
 
@@ -405,6 +411,7 @@ def run(integrand: Integrand, cfg: VegasConfig | None = None, *,
     larger ``max_it``).
     """
     from repro.engine import execute, make_plan
-    plan = make_plan(integrand, cfg)
-    return execute(plan, key=key, state=state, fill_fn=fill_fn,
-                   checkpoint_cb=checkpoint_cb)
+    with obs.span("repro.run"):
+        plan = make_plan(integrand, cfg)
+        return execute(plan, key=key, state=state, fill_fn=fill_fn,
+                       checkpoint_cb=checkpoint_cb)

@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 # Floor for damped weights: keeps every interval at non-zero width so the
 # Jacobian never degenerates (vegas' TINY).
 _TINY = 1e-30
@@ -104,17 +106,20 @@ def adapt_edges(edges: jax.Array, sums: jax.Array, counts: jax.Array, alpha) -> 
     damped weight; realized as piecewise-linear inversion of the cumulative
     weight via searchsorted (parallel; cuVegas does a sequential walk).
     """
-    ninc = edges.shape[1] - 1
-    w = _smooth_and_damp(sums, counts, alpha)          # (d, ninc)
+    with obs.scope("vegas.adapt_edges"):
+        ninc = edges.shape[1] - 1
+        w = _smooth_and_damp(sums, counts, alpha)          # (d, ninc)
 
-    def per_dim(edges_d, w_d):
-        cum = jnp.concatenate([jnp.zeros((1,), w_d.dtype), jnp.cumsum(w_d)])
-        targets = cum[-1] * jnp.arange(1, ninc, dtype=w_d.dtype) / ninc
-        j = jnp.clip(jnp.searchsorted(cum, targets, side="right") - 1, 0, ninc - 1)
-        frac = (targets - cum[j]) / jnp.maximum(w_d[j], _TINY)
-        new_mid = edges_d[j] + frac * (edges_d[j + 1] - edges_d[j])
-        new = jnp.concatenate([edges_d[:1], new_mid, edges_d[-1:]])
-        # Guard monotonicity against fp round-off in the interpolation.
-        return jax.lax.cummax(new, axis=0)
+        def per_dim(edges_d, w_d):
+            cum = jnp.concatenate([jnp.zeros((1,), w_d.dtype),
+                                   jnp.cumsum(w_d)])
+            targets = cum[-1] * jnp.arange(1, ninc, dtype=w_d.dtype) / ninc
+            j = jnp.clip(jnp.searchsorted(cum, targets, side="right") - 1,
+                         0, ninc - 1)
+            frac = (targets - cum[j]) / jnp.maximum(w_d[j], _TINY)
+            new_mid = edges_d[j] + frac * (edges_d[j + 1] - edges_d[j])
+            new = jnp.concatenate([edges_d[:1], new_mid, edges_d[-1:]])
+            # Guard monotonicity against fp round-off in the interpolation.
+            return jax.lax.cummax(new, axis=0)
 
-    return jax.vmap(per_dim)(edges, w)
+        return jax.vmap(per_dim)(edges, w)

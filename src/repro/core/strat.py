@@ -18,6 +18,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 
 def choose_nstrat(neval: int, dim: int, max_cubes: int = 1 << 20) -> int:
     """vegas' heuristic: ~(neval/2)^(1/dim) slices/dim, capped by max_cubes."""
@@ -46,19 +48,21 @@ def map_evals_to_cubes(n_h: jax.Array, n_cap: int):
     Returns ``(cube (n_cap,) int32, n_used scalar)``. Evals past the active
     total get cube id ``n_cubes`` (overflow bucket).
     """
-    cum = jnp.cumsum(n_h)
-    e = jnp.arange(n_cap, dtype=cum.dtype)
-    cube = jnp.searchsorted(cum, e, side="right").astype(jnp.int32)
-    return cube, cum[-1]
+    with obs.scope("vegas.cube_ids"):
+        cum = jnp.cumsum(n_h)
+        e = jnp.arange(n_cap, dtype=cum.dtype)
+        cube = jnp.searchsorted(cum, e, side="right").astype(jnp.int32)
+        return cube, cum[-1]
 
 
 def cubes_for_slice(n_h: jax.Array, start, length: int):
     """Cube ids for a contiguous slice [start, start+length) of the *global*
     eval axis. ``start`` may be traced (shard-local offsets under shard_map);
     evals past the active total get the overflow id ``n_cubes``."""
-    cum = jnp.cumsum(n_h)
-    e = start + jnp.arange(length, dtype=cum.dtype)
-    return jnp.searchsorted(cum, e, side="right").astype(jnp.int32)
+    with obs.scope("vegas.cube_ids"):
+        cum = jnp.cumsum(n_h)
+        e = start + jnp.arange(length, dtype=cum.dtype)
+        return jnp.searchsorted(cum, e, side="right").astype(jnp.int32)
 
 
 def cube_coords(cube: jax.Array, nstrat: int, dim: int) -> jax.Array:
@@ -76,11 +80,13 @@ def stratified_y(cube: jax.Array, u: jax.Array, nstrat: int) -> jax.Array:
 def adapt_nh(d_h: jax.Array, beta, neval: int, n_min: int = 2) -> jax.Array:
     """Re-allocate evals per cube: n_h = max(n_min, floor(neval * p_h)) with
     p_h = d_h^beta / sum d_h^beta (paper's damped stratification update)."""
-    d_h = jnp.maximum(d_h, 0.0)
-    p = d_h ** beta
-    tot = jnp.sum(p)
-    # A total at or under the clamp carries no signal: normalizing by the
-    # clamp instead of the total would drop sum(n_h) below neval - n_cubes.
-    p = jnp.where(tot > 1e-30, p / jnp.maximum(tot, 1e-30),
-                  1.0 / d_h.shape[0])
-    return jnp.maximum(jnp.floor(neval * p), n_min).astype(jnp.int32)
+    with obs.scope("vegas.adapt_nh"):
+        d_h = jnp.maximum(d_h, 0.0)
+        p = d_h ** beta
+        tot = jnp.sum(p)
+        # A total at or under the clamp carries no signal: normalizing by
+        # the clamp instead of the total would drop sum(n_h) below
+        # neval - n_cubes.
+        p = jnp.where(tot > 1e-30, p / jnp.maximum(tot, 1e-30),
+                      1.0 / d_h.shape[0])
+        return jnp.maximum(jnp.floor(neval * p), n_min).astype(jnp.int32)

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.batch.engine import BatchResult, scenario_keys
 from repro.core import integrator as core
 from repro.core import map as vmap_
@@ -150,47 +151,56 @@ def _execute_single(plan: Plan, key, state, fill_fn, checkpoint_cb,
             "StopPolicy (the on-device while_loop); checkpoint with a fixed "
             "loop, then resume the saved state under the stop policy")
 
-    if state is None:
-        state = core.init_state(integrand, cfg, key)
-    # The jitted step donates its input state; work on a copy so the caller's
-    # key / checkpointed state stay alive (resume safety).
-    state = jax.tree.map(jnp.copy, state)
-    if state.results.shape[0] < cfg.max_it:
-        # Resuming under a config with more iterations: grow the buffer.
-        pad = cfg.max_it - state.results.shape[0]
-        filler = jnp.stack([jnp.zeros((pad,), state.results.dtype),
-                            jnp.full((pad,), jnp.inf, state.results.dtype)], 1)
-        state = core.VegasState(state.edges, state.n_h, state.key, state.it,
-                                jnp.concatenate([state.results, filler]))
+    with obs.span("repro.init"):
+        if state is None:
+            state = core.init_state(integrand, cfg, key)
+        # The jitted step donates its input state; work on a copy so the
+        # caller's key / checkpointed state stay alive (resume safety).
+        state = jax.tree.map(jnp.copy, state)
+        if state.results.shape[0] < cfg.max_it:
+            # Resuming under a config with more iterations: grow the buffer.
+            pad = cfg.max_it - state.results.shape[0]
+            filler = jnp.stack([jnp.zeros((pad,), state.results.dtype),
+                                jnp.full((pad,), jnp.inf,
+                                         state.results.dtype)], 1)
+            state = core.VegasState(state.edges, state.n_h, state.key,
+                                    state.it,
+                                    jnp.concatenate([state.results, filler]))
+        start = int(state.it)
 
-    start = int(state.it)
     if checkpoint_cb is None:
         # On-device loop: one jitted program for the whole run (fori_loop,
         # or the stop policy's / iteration cap's fixed-shape while_loop).
-        prog = jax.jit(functools.partial(
-            core.run_loop, integrand=integrand, cfg=cfg, start=start,
-            fill_fn=fill_fn, stop=plan.stop), donate_argnums=0)
-        kw = ({} if it_cap is None
-              else {"it_cap": jnp.asarray(it_cap, jnp.int32)})
-        state = prog(state, **kw)
+        with obs.span("repro.program"):
+            prog = jax.jit(functools.partial(
+                core.run_loop, integrand=integrand, cfg=cfg, start=start,
+                fill_fn=fill_fn, stop=plan.stop), donate_argnums=0)
+            kw = ({} if it_cap is None
+                  else {"it_cap": jnp.asarray(it_cap, jnp.int32)})
+            state = prog(state, **kw)
     else:
         step = jax.jit(functools.partial(
             core.iteration_step, integrand=integrand, cfg=cfg,
             fill_fn=fill_fn), donate_argnums=0)
         end = cfg.max_it if it_cap is None else min(cfg.max_it, int(it_cap))
         for it in range(start, end):
-            state = step(state)
-            jax.block_until_ready(state.results)
+            with obs.span("repro.program"):
+                state = step(state)
+            with obs.span("repro.wait"):
+                jax.block_until_ready(state.results)
             checkpoint_cb(it, state)
 
-    n_it_used = int(state.it)
-    mean, sdev, chi2_dof, n_used = core.combine_results(
-        state.results, cfg.skip, n_it_used)
-    means, sig2 = state.results[:, 0], state.results[:, 1]
-    return core.VegasResult(float(mean), float(sdev), float(chi2_dof),
-                            int(n_used), means[:n_it_used],
-                            jnp.sqrt(sig2[:n_it_used]), state,
-                            n_it_used=n_it_used)
+    with obs.span("repro.wait"):
+        n_it_used = int(state.it)
+    obs.count("fill.lanes", (n_it_used - start) * cfg.n_cap)
+    with obs.span("repro.finish"):
+        mean, sdev, chi2_dof, n_used = core.combine_results(
+            state.results, cfg.skip, n_it_used)
+        means, sig2 = state.results[:, 0], state.results[:, 1]
+        return core.VegasResult(float(mean), float(sdev), float(chi2_dof),
+                                int(n_used), means[:n_it_used],
+                                jnp.sqrt(sig2[:n_it_used]), state,
+                                n_it_used=n_it_used)
 
 
 # --- batched family ----------------------------------------------------------
@@ -312,13 +322,19 @@ def _execute_family_vmap(plan: Plan, key, cache, *, keys=None, it_caps=None,
             raise ValueError(f"it_caps shape {caps.shape} != ({b},)")
         args.append(caps)
 
-    prog = make_family_program(plan, with_caps=it_caps is not None)
-    states, mean, sdev, chi2_dof, n_used = prog(*args)
+    with obs.span("repro.program"):
+        prog = make_family_program(plan, with_caps=it_caps is not None)
+        states, mean, sdev, chi2_dof, n_used = prog(*args)
+    with obs.span("repro.wait"):
+        it = np.asarray(states.it, dtype=np.int64)
+    # The vmapped loop runs every scenario until the last one stops.
+    obs.count("fill.lanes", int(it.max()) * b * cfg.n_cap)
 
-    if cache is not None:
-        cache.put(family, cfg, states.edges)
-    return package_batch_result(states, mean, sdev, chi2_dof, n_used,
-                                warm_started=warm)
+    with obs.span("repro.finish"):
+        if cache is not None:
+            cache.put(family, cfg, states.edges)
+        return package_batch_result(states, mean, sdev, chi2_dof, n_used,
+                                    warm_started=warm)
 
 
 def _execute_family_serial(plan: Plan, key, it_caps=None):

@@ -16,6 +16,7 @@ from typing import Any
 
 import jax.numpy as jnp
 
+from repro import obs
 from repro.batch.family import IntegrandFamily
 from repro.core import integrator as core
 
@@ -80,6 +81,11 @@ def make_plan(workload, cfg: core.VegasConfig | None = None,
     """Resolve + validate one run.  ``execution=None`` takes the config's own
     ``cfg.execution``; passing both lets callers keep one algorithm config
     and vary the execution axes (the sweep CLI does this)."""
+    with obs.span("repro.plan"):
+        return _make_plan(workload, cfg, execution)
+
+
+def _make_plan(workload, cfg, execution) -> Plan:
     cfg = cfg or core.VegasConfig()
     if execution is None:
         execution = cfg.execution

@@ -178,6 +178,7 @@ def vegas_fill(u, cube, edges_lo, widths, *, nstrat: int, n_cubes: int,
             jax.ShapeDtypeStruct((d, ninc), dtype),
         ],
         interpret=interpret,
+        name="vegas_fill",
     )(u, cube, edges_lo, widths, *flat_consts)
 
 
@@ -475,4 +476,5 @@ def vegas_fill_fused(key_bits, cube, edges_lo, widths, *, nstrat: int,
             jax.ShapeDtypeStruct((rows, LANE), accum),
         ],
         interpret=interpret,
+        name="vegas_fill_fused",
     )(first_in[0], cube, ew, *flat_consts)
