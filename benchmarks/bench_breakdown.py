@@ -113,6 +113,8 @@ def _phases(ig, neval, ninc=1024):
     state = I.init_state(ig, cfg, jax.random.PRNGKey(0))
     key = jax.random.fold_in(state.key, 0)
     dim, chunk, n_chunks = ig.dim, cfg.chunk, cfg.n_cap // cfg.chunk
+    cubes = strat.cubes_for_slice(state.n_h, 0, cfg.n_cap).reshape(
+        n_chunks, chunk)
 
     def scan(body):
         def prog(k):
@@ -131,7 +133,7 @@ def _phases(ig, neval, ninc=1024):
     # eval: transform + integrand on that stream (map lookup + jacobian).
     def eval_body(k, g):
         u = jax.random.uniform(jax.random.fold_in(k, g), (chunk, dim))
-        cube = strat.cubes_for_slice(state.n_h, g * chunk, chunk)
+        cube = cubes[g]
         w, _, _ = F._eval_chunk(state.edges, cube, u, ig, cfg.nstrat,
                                 cfg.n_cubes)
         return jnp.sum(w)
@@ -140,7 +142,7 @@ def _phases(ig, neval, ninc=1024):
     # accumulate/ref: the scatter-add program on precomputed (w, iy, cube).
     def acc_body(k, g):
         u = jax.random.uniform(jax.random.fold_in(k, g), (chunk, dim))
-        cube = strat.cubes_for_slice(state.n_h, g * chunk, chunk)
+        cube = cubes[g]
         w, iy, valid = F._eval_chunk(state.edges, cube, u, ig, cfg.nstrat,
                                      cfg.n_cubes)
         ms, _ = vmap_.accumulate_map_weights(iy, w * w,

@@ -103,12 +103,11 @@ def fill_reference(edges, n_h, key, integrand, *, nstrat: int, n_cap: int,
     if n_chunks is None:
         n_chunks = n_cap // chunk
 
-    def body(carry, step):
+    def body(carry, xs):
+        step, cube = xs
         acc, comp = carry if kahan else (carry, None)
-        gchunk = start_chunk + step
-        k = jax.random.fold_in(key, gchunk)
+        k = jax.random.fold_in(key, start_chunk + step)
         u = jax.random.uniform(k, (chunk, dim), dtype=dtype)
-        cube = strat.cubes_for_slice(n_h, gchunk * chunk, chunk)
         w, iy, valid = _eval_chunk(edges, cube, u, integrand, nstrat, n_cubes)
         w = w.astype(accum)
         w2 = w * w
@@ -128,7 +127,10 @@ def fill_reference(edges, n_h, key, integrand, *, nstrat: int, n_cap: int,
     zero = FillResult(jnp.zeros((dim, ninc), accum), jnp.zeros((dim, ninc), accum),
                       jnp.zeros((n_cubes,), accum), jnp.zeros((n_cubes,), accum))
     init = (zero, zero) if kahan else zero
-    out, _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
+    # The range's cube ids in one pass (4 bytes a lane), read a chunk a step.
+    cubes = strat.cubes_for_slice(n_h, start_chunk * chunk, n_chunks * chunk)
+    out, _ = jax.lax.scan(body, init, (jnp.arange(n_chunks),
+                                       cubes.reshape(n_chunks, chunk)))
     if kahan:
         return out if return_comp else out[0]
     return out

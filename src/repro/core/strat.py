@@ -7,8 +7,9 @@ cube's variance contribution (paper eq. (5)-(7)).
 
 Shapes must stay static under jit, so the eval axis has a fixed capacity
 ``n_cap`` and iterations that need fewer evals mask the tail (DESIGN.md C2):
-``mapEvalsToCubes`` is a searchsorted over ``cumsum(n_h)`` and out-of-range
-evals get cube id ``n_cubes`` (an overflow bucket that is dropped).
+``mapEvalsToCubes`` inverts ``cumsum(n_h)`` with one scatter of cube
+boundaries and a prefix sum over the eval axis, and out-of-range evals get
+cube id ``n_cubes`` (an overflow bucket that is dropped).
 """
 
 from __future__ import annotations
@@ -48,21 +49,25 @@ def map_evals_to_cubes(n_h: jax.Array, n_cap: int):
     Returns ``(cube (n_cap,) int32, n_used scalar)``. Evals past the active
     total get cube id ``n_cubes`` (overflow bucket).
     """
-    with obs.scope("vegas.cube_ids"):
-        cum = jnp.cumsum(n_h)
-        e = jnp.arange(n_cap, dtype=cum.dtype)
-        cube = jnp.searchsorted(cum, e, side="right").astype(jnp.int32)
-        return cube, cum[-1]
+    return cubes_for_slice(n_h, 0, n_cap), jnp.sum(n_h)
 
 
 def cubes_for_slice(n_h: jax.Array, start, length: int):
     """Cube ids for a contiguous slice [start, start+length) of the *global*
     eval axis. ``start`` may be traced (shard-local offsets under shard_map);
-    evals past the active total get the overflow id ``n_cubes``."""
+    evals past the active total get the overflow id ``n_cubes``.
+
+    The id of eval ``e`` is ``#{h : cumsum(n_h)[h] <= e}``: a 1 marks each
+    cube's end on the slice (ends at or before ``start`` pile up at 0, ends
+    past the slice are dropped) and the prefix sum of the marks counts them.
+    One scatter of ``n_cubes`` sorted indices and one prefix sum of
+    ``length`` lanes, so build a fill's whole range once, not per chunk.
+    """
     with obs.scope("vegas.cube_ids"):
-        cum = jnp.cumsum(n_h)
-        e = start + jnp.arange(length, dtype=cum.dtype)
-        return jnp.searchsorted(cum, e, side="right").astype(jnp.int32)
+        pos = jnp.maximum(jnp.cumsum(n_h) - start, 0)
+        marks = jnp.zeros((length,), jnp.int32).at[pos].add(
+            1, mode="drop", indices_are_sorted=True)
+        return jnp.cumsum(marks).astype(jnp.int32)
 
 
 def cube_coords(cube: jax.Array, nstrat: int, dim: int) -> jax.Array:

@@ -357,9 +357,8 @@ def fill(edges, n_h, key, integrand, *, nstrat: int, n_cap: int, chunk: int,
     widths = jnp.diff(edges, axis=1).astype(dtype)
     pure_ig, ig_consts = hoist_closure(integrand, (block, d), dtype)
 
-    def chunk_contrib(gchunk):
+    def chunk_contrib(gchunk, cube):
         k = jax.random.fold_in(key, gchunk)
-        cube = strat.cubes_for_slice(n_h, gchunk * chunk, chunk)
         u = (None if rng_in_kernel else
              jax.random.uniform(k, (chunk, d), dtype=dtype))
         ms, mc, s1p, s2p = vegas_fill_gpu(
@@ -370,8 +369,9 @@ def fill(edges, n_h, key, integrand, *, nstrat: int, n_cap: int, chunk: int,
         return FillResult(ms.reshape(d, ninc), mc.reshape(d, ninc),
                           s1p[:n_cubes], s2p[:n_cubes])
 
-    def body(carry, step):
-        contrib = chunk_contrib(start_chunk + step)
+    def body(carry, xs):
+        step, cube = xs
+        contrib = chunk_contrib(start_chunk + step, cube)
         if not kahan:
             return carry + contrib, None
         acc, comp = carry
@@ -383,7 +383,9 @@ def fill(edges, n_h, key, integrand, *, nstrat: int, n_cap: int, chunk: int,
     zero = FillResult(jnp.zeros((d, ninc), accum), jnp.zeros((d, ninc), accum),
                       jnp.zeros((n_cubes,), accum), jnp.zeros((n_cubes,), accum))
     init = (zero, zero) if kahan else zero
-    out, _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
+    cubes = strat.cubes_for_slice(n_h, start_chunk * chunk, n_chunks * chunk)
+    out, _ = jax.lax.scan(body, init, (jnp.arange(n_chunks),
+                                       cubes.reshape(n_chunks, chunk)))
     if kahan:
         return out if return_comp else out[0]
     return out

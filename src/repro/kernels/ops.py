@@ -3,10 +3,11 @@ contract (the 'pallas'/'pallas-fused' entries of the engine's backend
 registry, via core.fill.fill_pallas).
 
 The fill is scan-chunked exactly like ``core.fill.fill_reference``: chunk
-``g`` draws its uniforms from ``fold_in(key, g)`` and its cube ids from the
-global eval offset ``g * chunk``, so live memory is bounded by one chunk
-(never by ``n_cap``) and ``start_chunk``/``n_chunks`` select a contiguous
-chunk range — the unit ``dist.sharded_fill`` distributes (DESIGN.md C5).
+``g`` draws its uniforms from ``fold_in(key, g)`` and takes the cube ids of
+global evals ``[g * chunk, (g + 1) * chunk)`` from the range's ids, built
+once per call (4 bytes a lane).  Uniforms and weights live one chunk at a
+time, and ``start_chunk``/``n_chunks`` select a contiguous chunk range — the
+unit ``dist.sharded_fill`` distributes (DESIGN.md C5).
 
 Two kernel paths (DESIGN.md §7):
   * ``fused_cubes=False`` (P-V2 baseline): uniforms materialized per chunk in
@@ -248,9 +249,8 @@ def fill(edges, n_h, key, integrand, *, nstrat: int, n_cap: int, chunk: int,
     widths = jnp.diff(edges, axis=1).astype(dtype)
     pure_ig, ig_consts = hoist_closure(integrand, (tile, d), dtype)
 
-    def chunk_contrib(gchunk):
+    def chunk_contrib(gchunk, cube):
         k = jax.random.fold_in(key, gchunk)
-        cube = strat.cubes_for_slice(n_h, gchunk * chunk, chunk)
         if fused_cubes:
             u = (None if rng_in_kernel else
                  jax.random.uniform(k, (chunk, d), dtype=dtype))
@@ -276,8 +276,9 @@ def fill(edges, n_h, key, integrand, *, nstrat: int, n_cap: int, chunk: int,
         s2 = jnp.zeros((n_cubes + 1,), accum).at[cube].add(w * w)[:n_cubes]
         return FillResult(ms.astype(accum), mc.astype(accum), s1, s2)
 
-    def body(carry, step):
-        contrib = chunk_contrib(start_chunk + step)
+    def body(carry, xs):
+        step, cube = xs
+        contrib = chunk_contrib(start_chunk + step, cube)
         if not kahan:
             return carry + contrib, None
         acc, comp = carry
@@ -289,7 +290,9 @@ def fill(edges, n_h, key, integrand, *, nstrat: int, n_cap: int, chunk: int,
     zero = FillResult(jnp.zeros((d, ninc), accum), jnp.zeros((d, ninc), accum),
                       jnp.zeros((n_cubes,), accum), jnp.zeros((n_cubes,), accum))
     init = (zero, zero) if kahan else zero
-    out, _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
+    cubes = strat.cubes_for_slice(n_h, start_chunk * chunk, n_chunks * chunk)
+    out, _ = jax.lax.scan(body, init, (jnp.arange(n_chunks),
+                                       cubes.reshape(n_chunks, chunk)))
     if kahan:
         return out if return_comp else out[0]
     return out

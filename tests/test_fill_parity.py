@@ -123,6 +123,42 @@ def test_fill_parity_cubes_not_tile_multiple():
     _assert_fill_parity(dim=4, nstrat=3, chunk=512, n_chunks=2, tile=128)
 
 
+@pytest.mark.parametrize("fused,rng", [(False, None), (True, None),
+                                       (True, True)])
+def test_fill_parity_chunk_subrange_traced_start(fused, rng):
+    """A chunk sub-range (the shard unit) with a traced ``start_chunk``:
+    the kernel fill reads each chunk's ids at the range's offset, as
+    ``fill_reference`` does on the same range.  The allocation has
+    zero-size cubes and a masked tail that starts inside the range."""
+    dim, nstrat, chunk, n_chunks, ninc = 2, 5, 256, 6, 32
+    n_cubes, n_cap = nstrat**dim, chunk * n_chunks
+    key = jax.random.PRNGKey(11)
+    w = jax.random.uniform(jax.random.fold_in(key, 1), (dim, ninc),
+                           minval=0.05, maxval=1.0)
+    w = w / w.sum(1, keepdims=True)
+    edges = jnp.concatenate([jnp.zeros((dim, 1)), jnp.cumsum(w, axis=1)], 1)
+    n_h = jax.random.randint(jax.random.fold_in(key, 2), (n_cubes,), 0, 90,
+                             dtype=jnp.int32)
+    n_h = n_h.at[::4].set(0)
+    assert 2 * chunk < int(n_h.sum()) < 5 * chunk
+    kw = dict(nstrat=nstrat, n_cap=n_cap, chunk=chunk, n_chunks=3)
+
+    ref = fill_mod.fill_reference(edges, n_h, key, _ig, start_chunk=2, **kw)
+    pal = jax.jit(lambda s: fill_mod.fill_pallas(
+        edges, n_h, key, _ig, interpret=True, fused_cubes=fused, tile=64,
+        rng_in_kernel=rng, start_chunk=s, **kw))(jnp.int32(2))
+    head = fill_mod.fill_reference(edges, n_h, key, _ig, start_chunk=0, **kw)
+    for field in ("map_sums", "map_counts", "cube_s1", "cube_s2"):
+        a = np.asarray(getattr(ref, field))
+        b = np.asarray(getattr(pal, field))
+        scale = np.abs(a).max() or 1.0
+        np.testing.assert_allclose(
+            b, a, rtol=1e-4, atol=1e-5 * scale,
+            err_msg=f"{field} fused={fused} rng_in_kernel={rng}")
+        # The offset matters: the range's sums are not the first range's.
+        assert not np.allclose(a, np.asarray(getattr(head, field)))
+
+
 @pytest.mark.parametrize("fused", [False, True])
 def test_backend_configs_agree_through_full_run(fused):
     """End-to-end: a full adapted run under each backend lands within

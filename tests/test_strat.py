@@ -45,6 +45,48 @@ def test_map_evals_to_cubes_property(seed, n_cubes):
     assert counts[n_cubes] == n_cap - total
 
 
+#: Cube sizes with zero-size cubes, in runs and at either end: ends at
+#: 0, 3, 3, 5, 5, 5, 10, 11, 11, 15, 15.
+_N_H = np.array([0, 3, 0, 2, 0, 0, 5, 1, 0, 4, 0], np.int32)
+
+
+@pytest.mark.parametrize("how", ["eager", "jit", "vmap"])
+@pytest.mark.parametrize("length", [1, 13, 29])
+@pytest.mark.parametrize("start", [
+    0,    # the axis' start
+    1,    # mid-cube
+    3,    # on a boundary followed by a zero-size cube
+    5,    # on a boundary followed by two zero-size cubes
+    8,    # mid-cube
+    14,   # the last active eval
+    15,   # exactly the active total
+    40,   # past the total: every id is the overflow id
+])
+def test_cubes_for_slice_matches_searchsorted(start, length, how):
+    """The scatter-and-prefix-sum ids equal a searchsorted over
+    cumsum(n_h) exactly, for every offset, with ``start`` traced under jit
+    and with a batch of allocations under vmap."""
+    def oracle(n_h):
+        return np.searchsorted(np.cumsum(n_h), start + np.arange(length),
+                               side="right")
+
+    if how == "vmap":
+        rng = np.random.default_rng(start * 100 + length)
+        batch = np.stack([_N_H, _N_H[::-1], np.zeros_like(_N_H),
+                          rng.integers(0, 4, _N_H.shape).astype(np.int32)])
+        got = jax.vmap(lambda nh: strat.cubes_for_slice(nh, start, length))(
+            jnp.asarray(batch))
+        want = np.stack([oracle(nh) for nh in batch])
+    else:
+        f = lambda s: strat.cubes_for_slice(jnp.asarray(_N_H), s, length)
+        if how == "jit":
+            f = jax.jit(f)
+        got = f(jnp.int32(start) if how == "jit" else start)
+        want = oracle(_N_H)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_cube_coords_roundtrip():
     nstrat, dim = 4, 5
     ids = jnp.arange(nstrat**dim, dtype=jnp.int32)
