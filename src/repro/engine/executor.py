@@ -115,6 +115,17 @@ def execute(plan: Plan, *, key=None, state: core.VegasState | None = None,
                            it_cap=it_caps)
 
 
+def _count_fills(plan: Plan, fills: int) -> None:
+    """Add a run's ``fills`` (iterations, times B for a vmapped family) to
+    the counters: ``fill.lanes``, the lanes the fill ran, over every shard's
+    static chunk range (``n_cap`` unsharded); and on a mesh
+    ``mesh.psum_bytes``, the bytes each device all-reduced."""
+    obs.count("fill.lanes",
+              fills * sharding_mod.fill_lanes(plan.cfg, plan.n_shards))
+    if plan.n_shards > 1:
+        obs.count("mesh.psum_bytes", fills * sharding_mod.psum_bytes(plan.cfg))
+
+
 # --- single scenario ---------------------------------------------------------
 
 def _plan_fill_fn(plan: Plan, *, local: bool = False):
@@ -192,7 +203,7 @@ def _execute_single(plan: Plan, key, state, fill_fn, checkpoint_cb,
 
     with obs.span("repro.wait"):
         n_it_used = int(state.it)
-    obs.count("fill.lanes", (n_it_used - start) * cfg.n_cap)
+    _count_fills(plan, n_it_used - start)
     with obs.span("repro.finish"):
         mean, sdev, chi2_dof, n_used = core.combine_results(
             state.results, cfg.skip, n_it_used)
@@ -328,7 +339,7 @@ def _execute_family_vmap(plan: Plan, key, cache, *, keys=None, it_caps=None,
     with obs.span("repro.wait"):
         it = np.asarray(states.it, dtype=np.int64)
     # The vmapped loop runs every scenario until the last one stops.
-    obs.count("fill.lanes", int(it.max()) * b * cfg.n_cap)
+    _count_fills(plan, int(it.max()) * b)
 
     with obs.span("repro.finish"):
         if cache is not None:

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
+
 from . import backends as backends_mod
 
 REPLICATED = P()
@@ -46,6 +48,25 @@ def shard_chunk_range(total_chunks: int, shard: int, n_shards: int):
     """
     count = -(-total_chunks // n_shards)
     return shard * count, count
+
+
+def fill_lanes(rcfg, n_shards: int) -> int:
+    """Lanes one fill runs over all its shards: ``n_shards`` equal static
+    chunk ranges (the last may run past ``n_cap`` on dead chunks), or
+    ``n_cap`` unsharded."""
+    _, per_shard = shard_chunk_range(rcfg.n_cap // rcfg.chunk, 0, n_shards)
+    return n_shards * per_shard * rcfg.chunk
+
+
+def psum_bytes(rcfg) -> int:
+    """Bytes each device all-reduces in one sharded fill: the partial
+    :class:`FillResult` (two ``(d, ninc)`` map moments, two ``(n_cubes,)``
+    cube moments) and its Kahan compensation, in the accumulation dtype."""
+    prec = getattr(rcfg.execution, "precision", None)
+    accum = (prec.accum_dtype if prec is not None
+             and prec.accum_dtype is not None else rcfg.dtype)
+    per_result = 2 * rcfg.dim * rcfg.ninc + 2 * rcfg.n_cubes
+    return 2 * per_result * jnp.dtype(accum).itemsize
 
 
 def linear_shard_index(mesh, axis_names):
@@ -85,8 +106,9 @@ def make_local_fill(rcfg, mesh, axis_names, *, backend: str | None = None):
         part, comp = shard_fill(edges, n_h, key, integrand,
                                 start_chunk=idx * per_shard,
                                 n_chunks=per_shard)
-        total = jax.tree.map(lambda x: jax.lax.psum(x, axis_names), part)
-        resid = jax.tree.map(lambda x: jax.lax.psum(x, axis_names), comp)
+        with obs.scope("vegas.psum"):
+            total = jax.tree.map(lambda x: jax.lax.psum(x, axis_names), part)
+            resid = jax.tree.map(lambda x: jax.lax.psum(x, axis_names), comp)
         return jax.tree.map(jnp.subtract, total, resid)
 
     return fill

@@ -1,5 +1,6 @@
 """The program's own measurement (`repro.obs`): the host spans of a run, the
-device scopes of the loop, the lanes counter and the fill kernel's name."""
+device scopes of the loop, the lanes and psum counters, the psum scope and
+the fill kernel's name."""
 
 from pathlib import Path
 
@@ -78,6 +79,42 @@ def test_lanes_of_a_batched_family(stop):
     res = run_batch(fam, cfg, key=jax.random.PRNGKey(5))
     grew = obs.counts()["fill.lanes"] - before
     assert grew == 3 * int(res.n_it_used.max()) * n_cap
+
+
+@pytest.fixture(scope="module")
+def mesh_obs(mesh_worker):
+    """Counters and compiled all-reduces of runs on a 4-device mesh."""
+    return mesh_worker("obs")
+
+
+@pytest.mark.parametrize("run_kind", ["single", "batched"])
+def test_psum_bytes_of_a_mesh_run(mesh_obs, run_kind):
+    """Each fill all-reduces its partial FillResult and the partial's
+    compensation: 2 x (2 d ninc + 2 n_cubes) float32 values a device."""
+    m = mesh_obs
+    got = m[run_kind]
+    fills = (got["n_it"] if run_kind == "single"
+             else got["b"] * got["n_it_max"])
+    per_fill = 2 * (2 * m["dim"] * m["ninc"] + 2 * m["n_cubes"]) * 4
+    assert got["counted"]["mesh.psum_bytes"] == fills * per_fill
+    # The lanes are those the 4 shards run: equal static chunk ranges
+    # over every chunk of n_cap, the last range padded with dead chunks.
+    per_shard = -(-(m["n_cap"] // m["chunk"]) // 4)
+    assert got["counted"]["fill.lanes"] == fills * 4 * per_shard * m["chunk"]
+    assert 4 * per_shard * m["chunk"] > m["n_cap"]
+
+
+def test_no_psum_bytes_on_one_device():
+    ig = integrands.make_gaussian(dim=2, sigma=0.1)
+    before = obs.counts().get("mesh.psum_bytes", 0)
+    run(ig, small_cfg(rtol=0.05), key=jax.random.PRNGKey(3))
+    assert obs.counts().get("mesh.psum_bytes", 0) == before
+
+
+def test_psum_scope_names_the_all_reduces(mesh_obs):
+    ops = mesh_obs["all_reduces"]
+    assert ops
+    assert all('vegas.psum/psum"' in op for op in ops), ops
 
 
 def test_fused_kernel_is_named():

@@ -10,6 +10,7 @@ one process may load the TPU library at a time, and every test worker
 imports this file.
 """
 
+import functools
 import os
 
 import jax
@@ -22,8 +23,11 @@ from jax.sharding import PartitionSpec as P
 from repro.batch.family import make_gaussian_family
 from repro.core import VegasConfig
 from repro.core import integrands as igs
-from repro.engine import ExecutionConfig, PlanError, PrecisionPolicy, make_plan
+from repro.core import integrator as core
+from repro.engine import (ExecutionConfig, PlanError, PrecisionPolicy,
+                          StopPolicy, make_plan)
 from repro.engine import sharding as sharding_mod
+from repro.engine.executor import _plan_fill_fn
 from repro.kernels import ops as kops
 from repro.launch.integrate import INTEGRANDS
 
@@ -107,6 +111,42 @@ def test_sharded_fill_compiles_for_v5e_2x2(topo):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     assert "all-reduce" in hlo
+
+
+def test_sharded_run_compiles_for_v5e_2x2(topo):
+    """The whole-run program of the ``gaussian_d4_e7`` deployment, as
+    ``execute`` builds it: the stop policy's ``while_loop`` around the
+    shard_mapped fill, 4 shards of 160 chunks.  Both psums of an iteration
+    (partials and compensations) become all-reduces under ``vegas.psum``."""
+    ig = igs.make_gaussian(dim=4, mu=0.5, sigma=0.01)
+    mesh = Mesh(np.asarray(topo.devices).reshape(-1), ("data",))
+    cfg = VegasConfig(neval=NEVAL, ninc=NINC, chunk=CHUNK, max_it=20, skip=2,
+                      alpha=0.5, beta=0.75, max_cubes=2 ** 18,
+                      execution=ExecutionConfig(
+                          backend="pallas-fused", interpret=False, mesh=mesh,
+                          stop=StopPolicy(rtol=5e-5)))
+    plan = make_plan(ig, cfg)
+    rc = plan.cfg
+    assert (plan.n_shards, rc.n_cap // rc.chunk) == (4, 639)
+    rep = NamedSharding(mesh, P())
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=rep)
+    state = core.VegasState(spec((rc.dim, rc.ninc + 1), jnp.float32),
+                            spec((rc.n_cubes,), jnp.int32),
+                            spec((2,), jnp.uint32), spec((), jnp.int32),
+                            spec((rc.max_it, 2), jnp.float32))
+    prog = jax.jit(functools.partial(
+        core.run_loop, integrand=ig, cfg=rc, start=0,
+        fill_fn=_plan_fill_fn(plan), stop=plan.stop), donate_argnums=0)
+    compiled = prog.lower(state).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    reduces = [line for line in hlo.splitlines()
+               if " all-reduce(" in line or " all-reduce-start(" in line]
+    assert reduces and all("vegas.psum/" in r for r in reduces), reduces
+    # A device holds at most its shard's temporaries (2,621,440 cube ids are
+    # 10.5 MB of int32), never the whole axis's (42 MB).
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
 
 
 def test_widened_accumulation_refused_when_compiled_for_tpu():
