@@ -4,7 +4,9 @@ One entry point, :func:`execute`, composes the plan axes into a single
 program per run:
 
   * **single scenario** — `core.run_loop` as one jitted ``fori_loop``
-    program (or the host loop when a checkpoint policy is set);
+    program (or the host loop when a checkpoint policy is set), built once
+    per plan and kept in a bounded process-wide cache, so a repeated
+    ``core.run`` of one integrand and config reuses it (DESIGN.md §9);
   * **batched family**  — the whole loop ``vmap``ped over the scenario axis
     (`repro.batch` semantics: scenario ``b`` streams from ``fold_in(key,
     b)``, so batched == serial stream-for-stream);
@@ -28,6 +30,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
+from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
@@ -141,6 +145,57 @@ def _plan_fill_fn(plan: Plan, *, local: bool = False):
     return backends_mod.bind_fill(plan.cfg, backend=plan.backend.name)
 
 
+#: Whole-run programs of single-scenario plans that `execute` keeps for
+#: reuse, least recently used first out.
+_PROGRAM_CACHE_SIZE = 32
+_programs: OrderedDict[tuple, object] = OrderedDict()
+_programs_lock = threading.Lock()
+
+
+def _build_single_program(plan: Plan, start: int, fill_fn=None, *,
+                          donate: bool):
+    """The jitted ``core.run_loop`` of a single-scenario plan from iteration
+    ``start``: ``prog(state[, it_cap=...]) -> state``."""
+    if fill_fn is None:
+        fill_fn = _plan_fill_fn(plan)
+    return jax.jit(functools.partial(
+        core.run_loop, integrand=plan.workload, cfg=plan.cfg, start=start,
+        fill_fn=fill_fn, stop=plan.stop),
+        donate_argnums=0 if donate else ())
+
+
+def _cached_single_program(plan: Plan, start: int, with_cap: bool):
+    """The plan's donating whole-run program, built on the first call for
+    its key and reused after: jit's own cache is keyed on the function
+    object, so a program built anew on every call is traced, lowered and
+    fetched anew.  The key holds everything the traced program depends on,
+    as objects and not ids, so a collected integrand never aliases a new
+    one.  A key that does not hash (say, an integrand with list bounds)
+    builds its program uncached."""
+    key = (plan.workload, plan.cfg, plan.backend.name, plan.mesh,
+           plan.shard_axes, plan.stop, plan.precision, start, with_cap)
+    try:
+        hash(key)
+    except TypeError:
+        return _build_single_program(plan, start, donate=True)
+    with _programs_lock:
+        prog = _programs.pop(key, None)
+        built = prog is None
+        if built:
+            prog = _build_single_program(plan, start, donate=True)
+        _programs[key] = prog
+        if len(_programs) > _PROGRAM_CACHE_SIZE:
+            _programs.popitem(last=False)
+    obs.count("program.built" if built else "program.reused", 1)
+    return prog
+
+
+def _clear_program_cache() -> None:
+    """Drop every cached program (for tests that patch traced code)."""
+    with _programs_lock:
+        _programs.clear()
+
+
 def _execute_single(plan: Plan, key, state, fill_fn, checkpoint_cb,
                     it_cap=None):
     cfg, integrand = plan.cfg, plan.workload
@@ -149,8 +204,6 @@ def _execute_single(plan: Plan, key, state, fill_fn, checkpoint_cb,
             f"a single-scenario run takes a scalar it_cap, got shape "
             f"{jnp.shape(it_cap)} (per-scenario caps are a batched-family "
             f"feature)")
-    if fill_fn is None:
-        fill_fn = _plan_fill_fn(plan)
     if checkpoint_cb is None and plan.checkpoint is not None:
         checkpoint_cb = plan.checkpoint.build_callback()
     if checkpoint_cb is not None and plan.stop is not None:
@@ -183,16 +236,19 @@ def _execute_single(plan: Plan, key, state, fill_fn, checkpoint_cb,
         # On-device loop: one jitted program for the whole run (fori_loop,
         # or the stop policy's / iteration cap's fixed-shape while_loop).
         with obs.span("repro.program"):
-            prog = jax.jit(functools.partial(
-                core.run_loop, integrand=integrand, cfg=cfg, start=start,
-                fill_fn=fill_fn, stop=plan.stop), donate_argnums=0)
+            # A caller's fill_fn says nothing by its identity about what
+            # it traces: its program is built for this call alone.
+            prog = (_cached_single_program(plan, start, it_cap is not None)
+                    if fill_fn is None else
+                    _build_single_program(plan, start, fill_fn, donate=True))
             kw = ({} if it_cap is None
                   else {"it_cap": jnp.asarray(it_cap, jnp.int32)})
             state = prog(state, **kw)
     else:
         step = jax.jit(functools.partial(
             core.iteration_step, integrand=integrand, cfg=cfg,
-            fill_fn=fill_fn), donate_argnums=0)
+            fill_fn=_plan_fill_fn(plan) if fill_fn is None else fill_fn),
+            donate_argnums=0)
         end = cfg.max_it if it_cap is None else min(cfg.max_it, int(it_cap))
         for it in range(start, end):
             with obs.span("repro.program"):
@@ -227,7 +283,7 @@ def uniform_family_edges(family, cfg, b: int):
 def make_single_program(plan: Plan):
     """Build the jitted whole-run program of a single-scenario plan ONCE,
     for callers that run the same plan repeatedly — ``prog(state) ->
-    state``.  Unlike the per-call program inside :func:`execute` it does not
+    state``.  Unlike the cached program inside :func:`execute` it does not
     donate its input, so one initial state can be replayed; steady-state
     timing (``benchmarks/bench_runs.py``, `engine.autotune.calibrate`)
     needs exactly this — the knob effects the cost model fits are several
@@ -237,10 +293,7 @@ def make_single_program(plan: Plan):
         raise ValueError("make_single_program builds the single-scenario "
                          "on-device loop; use make_family_program for "
                          "batched plans")
-    fill_fn = _plan_fill_fn(plan)
-    return jax.jit(functools.partial(
-        core.run_loop, integrand=plan.workload, cfg=plan.cfg, start=0,
-        fill_fn=fill_fn, stop=plan.stop))
+    return _build_single_program(plan, 0, donate=False)
 
 
 def make_family_program(plan: Plan, *, with_caps: bool = False):

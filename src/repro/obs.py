@@ -11,7 +11,10 @@
   the device trace carries; it adds no device work.
 * :func:`count` adds to a process-wide integer counter and :func:`counts`
   snapshots all of them.  Counters only grow: a reader takes the difference
-  of two snapshots around the work it measures.
+  of two snapshots around the work it measures.  The program counts
+  ``fill.lanes`` (lanes the fill ran), ``mesh.psum_bytes`` (bytes each
+  device all-reduced), and ``program.built`` / ``program.reused`` (the
+  executor's whole-run program cache missed / hit).
 
 Nothing is exported or switched on here: a reader (the benchmark under
 ``bench/``) starts the profiler and snapshots the counters itself.

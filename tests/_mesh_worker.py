@@ -28,6 +28,7 @@ from repro.batch.family import make_gaussian_family  # noqa: E402
 from repro.core import VegasConfig, integrands, run  # noqa: E402
 from repro.core import integrator as core  # noqa: E402
 from repro.engine import ExecutionConfig, StopPolicy, make_plan  # noqa: E402
+from repro.engine import executor  # noqa: E402
 from repro.engine.executor import _plan_fill_fn  # noqa: E402
 from repro.launch.mesh import make_local_mesh  # noqa: E402
 
@@ -67,6 +68,24 @@ def one_run(backend, mesh):
             "results": np.asarray(r.state.results[:r.n_it_used]).tolist()}
 
 
+def reuse_check(mesh):
+    """Two sharded runs of one plan: the programs they built and reused, and
+    whether each result is bitwise that of a program built afresh for it."""
+    ig, cfg = gaussian(), vegas_config("ref", mesh)
+    keys = [jax.random.PRNGKey(k) for k in (1, 2)]
+    executor._clear_program_cache()
+    runs, counted = grew(lambda: [run(ig, cfg, key=k) for k in keys])
+    fresh = []
+    for k in keys:
+        executor._clear_program_cache()
+        fresh.append(run(ig, cfg, key=k))
+    return {"built": counted.get("program.built", 0),
+            "reused": counted.get("program.reused", 0),
+            "bitwise": all(np.array_equal(np.asarray(a.state.results),
+                                          np.asarray(b.state.results))
+                           for a, b in zip(runs, fresh))}
+
+
 def run_mode():
     mesh = make_local_mesh()
     sz = reference.sizes(CONFIG)
@@ -81,6 +100,7 @@ def run_mode():
         m["combined_f64"] = reference.combine(res[:, 0], res[:, 1],
                                               CONFIG["skip"])
         out[backend] = {"mesh": m, "one_device": one}
+    out["reuse"] = reuse_check(mesh)
     return out
 
 

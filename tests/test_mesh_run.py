@@ -72,3 +72,9 @@ def test_mesh_run_meets_rtol_like_one_device(runs, backend):
     assert abs(mesh["mean"] - one["mean"]) <= 3 * max(mesh["sdev"],
                                                       one["sdev"])
     assert abs(mesh["mean"] - runs["exact"]) <= 4 * mesh["sdev"]
+
+
+def test_sharded_runs_of_one_plan_reuse_one_program(runs):
+    """The second run of a sharded plan reuses the first's program, and
+    gives bitwise what a program built afresh gives."""
+    assert runs["reuse"] == {"built": 1, "reused": 1, "bitwise": True}
