@@ -158,37 +158,6 @@ def fill_pallas(edges, n_h, key, integrand, *, nstrat: int, n_cap: int,
                      return_comp=return_comp, rng_in_kernel=rng_in_kernel)
 
 
-def fill_pallas_gpu(edges, n_h, key, integrand, *, nstrat: int, n_cap: int,
-                    chunk: int, dtype=jnp.float32, accum_dtype=None,
-                    interpret: bool | None = None, block: int | None = None,
-                    num_warps: int | None = None, start_chunk=0,
-                    n_chunks: int | None = None, kahan: bool = False,
-                    return_comp: bool = False,
-                    rng_in_kernel: bool | None = None) -> FillResult:
-    """Triton-lowered fill (the ``pallas-gpu`` registry backend): grid over
-    sample blocks, block-privatized histograms flushed with atomic adds,
-    scatter-style cube accumulation — the fused kernel reshaped for a GPU
-    memory hierarchy instead of an MXU (DESIGN.md §14).  Same scan-chunked
-    contract and bit-identical chunk-keyed RNG as the other backends;
-    ``interpret=None`` autodetects (compiled Triton on GPU, interpreter
-    elsewhere), ``block=None`` autotunes against the shared-memory budget."""
-    from repro.kernels import gpu_fill
-    return gpu_fill.fill(edges, n_h, key, integrand, nstrat=nstrat,
-                         n_cap=n_cap, chunk=chunk, dtype=dtype,
-                         accum_dtype=accum_dtype,
-                         interpret=interpret, block=block,
-                         num_warps=num_warps, start_chunk=start_chunk,
-                         n_chunks=n_chunks, kahan=kahan,
-                         return_comp=return_comp,
-                         rng_in_kernel=rng_in_kernel)
-
-
-# Backend selection lives in the capability-declaring registry
-# (repro.engine.backends): 'ref' -> fill_reference, 'pallas' (P-V2) and
-# 'pallas-fused' (P-V3) -> fill_pallas with the fusion knob pinned,
-# 'pallas-gpu' -> fill_pallas_gpu (the Triton lowering).
-
-
 def estimate_from_cubes(res: FillResult, n_h: jax.Array):
     """Iteration estimate + variance + stratification signal (eq. (5)-(7)).
 

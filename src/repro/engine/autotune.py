@@ -11,13 +11,12 @@ ports self-tuning instead of re-defaulted.
 
 Three layers:
 
-  * **calibration** (:func:`calibrate`, driven by
-    ``benchmarks/bench_calibrate.py``): time the jitted fill hot path over a
-    small grid of (backend, d, neval, chunk, tile) shapes — steady-state,
+  * **calibration** (:func:`calibrate`): time the jitted fill hot path over
+    a small grid of (backend, d, neval, chunk, tile) shapes — steady-state,
     compile excluded — and fit per-class :class:`ClassCoeffs` by
     non-negative least squares.  The fitted :class:`CostTable` is keyed by
-    (device kind, jax backend, git sha) and persists as JSON next to the
-    BENCH_*.json artifacts;
+    (device kind, jax backend, git sha) and persists as JSON
+    (:meth:`CostTable.save`);
   * **prediction** (:meth:`ClassCoeffs.fill_s` / :func:`predict_run_s`):
     given a plan's geometry (d, ninc, n_cubes, neval, B, mesh), predict wall
     time for any candidate knob combination.  All coefficients are
@@ -45,16 +44,16 @@ import dataclasses
 import json
 import os
 import subprocess
-from typing import Any, Callable, Mapping
+from typing import Any, Mapping
 
 import numpy as np
 
-#: Default on-disk table name (written next to BENCH_*.json by
-#: ``benchmarks/bench_calibrate.py``, read back by ``resolve_table``).
+#: Default on-disk table name (``CostTable.save`` of a :func:`calibrate`
+#: run, read back by ``resolve_table``).
 DEFAULT_TABLE_PATH = "COST_TABLE.json"
 
-#: Environment variable naming a table file (CI's autotune-smoke job sets it
-#: so every --autotune run in the job shares one calibration).
+#: Environment variable naming a table file, so every ``--autotune`` run
+#: of a shell shares one calibration.
 TABLE_ENV = "REPRO_COST_TABLE"
 
 #: Candidate chunk sizes the tuner enumerates (powers of two; the caller's
@@ -72,29 +71,19 @@ def _git_sha() -> str:
         return "unknown"
 
 
-def device_kind() -> str:
-    """The cost-table device key, e.g. ``'cpu'`` / ``'TPU v4'``."""
-    import jax
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
-
-
 def class_key(backend: str, interpret: bool | None = None) -> str:
     """The cost-model class of a (backend, execution-mode) pair, qualified
     by the device that produced the timings (``'ref@cpu'``,
-    ``'pallas-gpu|compiled@NVIDIA H100'``).
+    ``'pallas-fused|compiled@TPU v5 lite'``).
 
     Backends without an ``interpret`` knob key by name alone; pallas
     backends split interpreter vs compiled timings into separate classes
     (``'pallas-fused|interpret'``) because the two are orders of magnitude
-    apart — one fitted line cannot cover both.  The execution mode resolves
-    against the backend's declared ``family`` (Mosaic kernels compile on
-    TPU, the Triton one on GPU), and the ``@device_kind`` qualifier keeps
-    timings from different silicon apart the same way — an A100 fit must
-    not predict for an H100.  Lookup falls back to the unqualified class
-    (`CostTable.coeffs`), so pre-qualification tables keep working.
+    apart — one fitted line cannot cover both.  The ``@device_kind``
+    qualifier keeps timings from different silicon apart the same way — a
+    v4 fit must not predict for a v5e.  Lookup falls back to the
+    unqualified class (`CostTable.coeffs`), so pre-qualification tables
+    keep working.
     """
     from repro import kernels
     from . import backends as backends_mod
@@ -102,11 +91,10 @@ def class_key(backend: str, interpret: bool | None = None) -> str:
     if "interpret" not in spec.knobs:
         base = backend
     else:
-        mode = ("interpret"
-                if kernels.resolve_interpret(interpret, spec.family)
+        mode = ("interpret" if kernels.resolve_interpret(interpret)
                 else "compiled")
         base = f"{backend}|{mode}"
-    return f"{base}@{device_kind()}"
+    return f"{base}@{kernels.device_kind()}"
 
 
 # --- the fitted model --------------------------------------------------------
@@ -175,17 +163,6 @@ BUILTIN_CLASSES: Mapping[str, ClassCoeffs] = {
     "pallas-fused|compiled": ClassCoeffs(c_fixed=1e-4, c_eval_dim=2e-10,
                                          c_chunk=2e-5, c_tile_step=2e-6,
                                          iter_overhead_s=2e-4),
-    # The Triton kernel interprets a little slower than the Mosaic one (the
-    # per-block one-hot partials cost more under the interpreter than the
-    # windowed matmul); compiled estimates sit at the paper's GPU fill
-    # throughput order of magnitude (cuVegas Table 1, ~1e9 evals/s) until a
-    # real-GPU calibration lands a measured '...@<device_kind>' class.
-    "pallas-gpu|interpret": ClassCoeffs(c_fixed=5e-3, c_eval_dim=4e-6,
-                                        c_chunk=2e-3, c_tile_step=2e-4,
-                                        iter_overhead_s=1e-3),
-    "pallas-gpu|compiled": ClassCoeffs(c_fixed=5e-5, c_eval_dim=3e-10,
-                                       c_chunk=1e-5, c_tile_step=1e-6,
-                                       iter_overhead_s=2e-4),
 }
 
 
@@ -263,8 +240,8 @@ def resolve_table(cost_table: Any = None) -> CostTable:
 
       1. ``cost_table`` (an `ExecutionConfig.cost_table`: a `CostTable` or a
          path string);
-      2. ``$REPRO_COST_TABLE`` (CI's autotune-smoke job);
-      3. ``./COST_TABLE.json`` (what ``bench_calibrate`` writes);
+      2. ``$REPRO_COST_TABLE`` (:data:`TABLE_ENV`);
+      3. ``./COST_TABLE.json`` (:data:`DEFAULT_TABLE_PATH`);
       4. the builtin order-of-magnitude table.
 
     A missing/unreadable explicit path raises; the implicit fallbacks are
@@ -357,12 +334,10 @@ def _time_steady(fn, *args, repeats: int = 2) -> float:
 
 
 def _fill_sample(backend: str, dim: int, neval: int, chunk: int,
-                 step: int | None, *, step_knob: str = "tile",
-                 ninc: int = 64, repeats: int = 2) -> dict:
-    """Time one jitted steady-state fill of one (backend, shape, knob)
-    point; returns the fitted-feature sample.  ``step`` is the backend's
-    grid-step knob (``tile`` on the Mosaic kernels, ``block`` on the Triton
-    one) — both fit the same per-grid-step cost feature."""
+                 tile: int | None, *, ninc: int = 64,
+                 repeats: int = 2) -> dict:
+    """Time one jitted steady-state fill of one (backend, shape, tile)
+    point; returns the fitted-feature sample."""
     import functools
 
     import jax
@@ -374,7 +349,7 @@ def _fill_sample(backend: str, dim: int, neval: int, chunk: int,
     from .config import ExecutionConfig
     from . import backends as backends_mod
 
-    execution = ExecutionConfig(backend=backend, **{step_knob: step})
+    execution = ExecutionConfig(backend=backend, tile=tile)
     cfg = core.VegasConfig(neval=neval, ninc=ninc, chunk=chunk,
                            execution=execution)
     rcfg = cfg.resolve(dim)
@@ -387,7 +362,7 @@ def _fill_sample(backend: str, dim: int, neval: int, chunk: int,
         lambda e, n, k, f: f(e, n, k, ig), f=fill_fn))
     seconds = _time_steady(prog, edges, n_h, key, repeats=repeats)
     return dict(b=1, d=dim, n_cap=rcfg.n_cap,
-                n_chunks=rcfg.n_cap // rcfg.chunk, tile=step,
+                n_chunks=rcfg.n_cap // rcfg.chunk, tile=tile,
                 chunk=rcfg.chunk, neval=neval, seconds=seconds)
 
 
@@ -416,20 +391,19 @@ def _iter_overhead(dim: int = 4, neval: int = 16_384,
 
 
 def calibrate(*, fast: bool = True, backends: tuple[str, ...] | None = None,
-              repeats: int = 2,
-              emit: Callable[[str, dict], None] | None = None) -> CostTable:
+              repeats: int = 2) -> CostTable:
     """Measure the fill/adapt hot paths over the calibration grid and fit a
     :class:`CostTable` for the current device.
 
     ``backends=None`` calibrates every registry backend in its
-    platform-resolved execution mode (interpreted pallas on CPU/GPU,
-    compiled on TPU).  ``emit(name, sample)`` lets the benchmark harness
-    record each measured point as a BENCH row.
+    platform-resolved execution mode (interpreted pallas off TPU, compiled
+    on TPU).
     """
     import time as _time
 
     import jax
 
+    from repro import kernels
     from . import backends as backends_mod
 
     t0 = _time.perf_counter()
@@ -440,30 +414,21 @@ def calibrate(*, fast: bool = True, backends: tuple[str, ...] | None = None,
     for backend in backends:
         spec = backends_mod.get(backend)
         key = class_key(backend)
-        step_knob = next((k for k in ("tile", "block") if k in spec.knobs),
-                         None)
         grid = ((_PALLAS_GRID_FAST if fast else _PALLAS_GRID_FULL)
-                if step_knob
+                if "tile" in spec.knobs
                 else (_REF_GRID_FAST if fast else _REF_GRID_FULL))
         samples = []
         for d in grid["dims"]:
             for neval in grid["nevals"]:
                 for chunk in grid["chunks"]:
-                    for step in grid.get("tiles", (None,)) if step_knob \
-                            else (None,):
-                        s = _fill_sample(backend, d, neval, chunk, step,
-                                         step_knob=step_knob or "tile",
+                    for tile in grid.get("tiles", (None,)):
+                        s = _fill_sample(backend, d, neval, chunk, tile,
                                          repeats=repeats)
                         s["class"] = key
                         samples.append(s)
-                        if emit is not None:
-                            emit(f"calibrate/{key}/d={d}/neval={neval}"
-                                 f"/chunk={s['chunk']}"
-                                 + (f"/{step_knob}={step}" if step else ""),
-                                 s)
         classes[key] = dataclasses.replace(fit_class(samples),
                                            iter_overhead_s=overhead)
-    return CostTable(device_kind=device_kind(),
+    return CostTable(device_kind=kernels.device_kind(),
                      jax_backend=jax.default_backend(), git_sha=_git_sha(),
                      source="calibrated",
                      calibration_wall_s=_time.perf_counter() - t0,
@@ -529,24 +494,17 @@ def _accum_itemsize(execution) -> int:
     return 4
 
 
-def _step_candidates(step_knob: str, chunk: int, d: int, ninc: int,
-                     n_cubes: int, accum_itemsize: int = 4) -> list:
-    """A small predicted-orderable subset of the kernel's valid grid steps
-    (``tile`` on the Mosaic kernels, ``block`` on the Triton one): the
+def _tile_candidates(chunk: int, d: int, ninc: int, n_cubes: int,
+                     accum_itemsize: int = 4) -> list:
+    """A small predicted-orderable subset of the kernel's valid tiles: the
     static-autotune choice plus the power-of-two divisors >= 8.  All
     candidates come from the kernel's own validity oracle
-    (``ops.valid_tiles`` / ``gpu_fill.valid_blocks``), so the tuner can
-    never pick a step ``_pick_tile``/``_pick_block`` rejects — including
-    under a widened policy, where the 8-byte accumulators shrink the valid
-    set (``accum_itemsize``)."""
-    if step_knob == "block":
-        from repro.kernels import gpu_fill
-        valid = gpu_fill.valid_blocks(chunk, d, ninc,
-                                      accum_itemsize=accum_itemsize)
-    else:
-        from repro.kernels import ops
-        valid = ops.valid_tiles(chunk, d, ninc, n_cubes,
-                                accum_itemsize=accum_itemsize)
+    (``ops.valid_tiles``), so the tuner can never pick a tile
+    ``_pick_tile`` rejects — including under a widened policy, where the
+    8-byte accumulators shrink the valid set (``accum_itemsize``)."""
+    from repro.kernels import ops
+    valid = ops.valid_tiles(chunk, d, ninc, n_cubes,
+                            accum_itemsize=accum_itemsize)
     if not valid:
         return [None]     # let the kernel's own picker raise its diagnostic
     pow2 = [t for t in valid if t >= 8 and (t & (t - 1)) == 0]
@@ -585,23 +543,18 @@ def tune(workload, cfg, *, table: CostTable | None = None):
     family = _is_family(workload)
     b = workload.batch_size if family else 1
     probe_exec = dataclasses.replace(execution, autotune=False)
-    step_knob = next((k for k in ("tile", "block") if k in spec.knobs), None)
-    pinned_step = getattr(execution, step_knob) if step_knob else None
+    tiled = "tile" in spec.knobs
+    pinned_tile = execution.tile if tiled else None
     itemsize = _accum_itemsize(execution)
 
     # The default-knob baseline the report compares against.
     base_rcfg = cfg.resolve(dim)
-    default_step = pinned_step
-    if step_knob == "tile" and default_step is None:
+    default_tile = pinned_tile
+    if tiled and default_tile is None:
         from repro.kernels import ops
-        default_step = ops.autotune_tile(base_rcfg.chunk, dim,
+        default_tile = ops.autotune_tile(base_rcfg.chunk, dim,
                                          base_rcfg.ninc, base_rcfg.n_cubes,
                                          accum_itemsize=itemsize)
-    elif step_knob == "block" and default_step is None:
-        from repro.kernels import gpu_fill
-        default_step = gpu_fill.autotune_block(base_rcfg.chunk, dim,
-                                               base_rcfg.ninc,
-                                               accum_itemsize=itemsize)
     mesh = execution.mesh
     default_axes = (execution.shard_axes if execution.shard_axes is not None
                     else (tuple(mesh.axis_names) if mesh is not None else None))
@@ -612,17 +565,16 @@ def tune(workload, cfg, *, table: CostTable | None = None):
     default_vmap = family and (default_batch == "vmap" or (
         default_batch == "auto" and vmappable))
 
-    def predict(rcfg, step, n_shards, vmapped):
-        # tile= is the generic per-grid-step feature; block fits it too.
+    def predict(rcfg, tile, n_shards, vmapped):
         if vmapped or not family:
-            return predict_run_s(coeffs, rcfg, b=b, tile=step,
+            return predict_run_s(coeffs, rcfg, b=b, tile=tile,
                                  n_shards=n_shards)
         # Serial family: B independent programs, each paying c_fixed +
         # overhead on its own.
-        return b * predict_run_s(coeffs, rcfg, b=1, tile=step,
+        return b * predict_run_s(coeffs, rcfg, b=1, tile=tile,
                                  n_shards=n_shards)
 
-    predicted_default = predict(base_rcfg, default_step, default_shards,
+    predicted_default = predict(base_rcfg, default_tile, default_shards,
                                 default_vmap)
 
     # --- candidate enumeration ----------------------------------------------
@@ -644,31 +596,30 @@ def tune(workload, cfg, *, table: CostTable | None = None):
     for chunk in chunk_cands:
         ccfg = dataclasses.replace(cfg, chunk=chunk, execution=probe_exec)
         rcfg = ccfg.resolve(dim)
-        steps = ([pinned_step] if step_knob is None
-                 or pinned_step is not None
-                 else _step_candidates(step_knob, rcfg.chunk, dim,
-                                       rcfg.ninc, rcfg.n_cubes, itemsize))
-        for step in steps:
+        tiles = ([pinned_tile] if not tiled or pinned_tile is not None
+                 else _tile_candidates(rcfg.chunk, dim, rcfg.ninc,
+                                       rcfg.n_cubes, itemsize))
+        for tile in tiles:
             for axes in axes_cands:
                 n_sh = (sharding_mod.mesh_shard_count(mesh, axes)
                         if mesh is not None and axes else 1)
                 for bm in batch_cands:
-                    pred = predict(rcfg, step, n_sh, bm != "serial")
-                    combos.append((pred, chunk, step, axes, bm))
+                    pred = predict(rcfg, tile, n_sh, bm != "serial")
+                    combos.append((pred, chunk, tile, axes, bm))
     # Stable sort on predicted cost alone: equal predictions keep candidate
     # order, and the caller's own chunk sorts via its position in the sorted
     # candidate list — deterministic for a fixed table (property-tested).
     combos.sort(key=lambda c: c[0])
 
     # --- probe: validity is make_plan's, not ours ---------------------------
-    for pred, chunk, step, axes, bm in combos:
-        # A tile/block on a backend without the knob is forwarded unchanged
-        # (it rides along inside probe_exec) so the probe — and the fallback
-        # — surface make_plan's own knob PlanError: the tuner must never
+    for pred, chunk, tile, axes, bm in combos:
+        # A tile on a backend without the knob is forwarded unchanged (it
+        # rides along inside probe_exec) so the probe — and the fallback —
+        # surface make_plan's own knob PlanError: the tuner must never
         # launder an invalid pin into a valid plan.
         cand_exec = dataclasses.replace(
             probe_exec, shard_axes=axes, batch=bm,
-            **({step_knob: step} if step_knob else {}))
+            **({"tile": tile} if tiled else {}))
         cand_cfg = dataclasses.replace(cfg, chunk=chunk,
                                        execution=cand_exec)
         try:
@@ -680,10 +631,10 @@ def tune(workload, cfg, *, table: CostTable | None = None):
             device_kind=table.device_kind,
             chosen=dict(chunk=cand_cfg.resolve(dim).chunk, batch=bm,
                         shard_axes=axes,
-                        **({step_knob: step} if step_knob else {})),
+                        **({"tile": tile} if tiled else {})),
             default=dict(chunk=base_rcfg.chunk, batch=execution.batch,
                          shard_axes=execution.shard_axes,
-                         **({step_knob: default_step} if step_knob else {})),
+                         **({"tile": default_tile} if tiled else {})),
             predicted_s=pred, predicted_default_s=predicted_default)
         return cand_cfg, report
     # Nothing the model proposed validates (e.g. an exotic workload the

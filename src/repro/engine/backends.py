@@ -43,9 +43,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 from typing import Any, Callable, Mapping
 
 from repro.core import fill as fill_mod
+
+log = logging.getLogger("repro.engine")
 
 SHARDABLE = "shardable"
 VMAPPABLE = "vmappable"
@@ -73,9 +76,6 @@ class BackendSpec:
     #: (the threefry mantissa trick reproduces the f32 uniform bit pattern);
     #: widened f32->f64 accumulation is a separate, declarable capability.
     precisions: tuple[tuple[str, str], ...] = (("float32", "float32"),)
-    family: str = "tpu"               # platform that compiles this kernel
-                                      # natively (kernels.resolve_interpret);
-                                      # irrelevant without an 'interpret' knob
     doc: str = ""
 
     def supports(self, capability: str) -> bool:
@@ -190,14 +190,30 @@ register(BackendSpec(
     doc="P-V3 streaming kernel: in-kernel RNG + in-kernel cube moments",
 ))
 
-register(BackendSpec(
-    name="pallas-gpu",
-    fill=fill_mod.fill_pallas_gpu,
-    capabilities=frozenset({SHARDABLE, VMAPPABLE, IN_KERNEL_RNG,
-                            CLOSURE_HOISTING, EARLY_STOP}),
-    knobs=("interpret", "block", "num_warps"),
-    precisions=(("float32", "float32"), ("float32", "float64")),
-    family="gpu",
-    doc="Triton-lowered fill: scatter/atomic cube accumulation, "
-        "block-privatized histograms, in-kernel RNG (DESIGN.md §14)",
-))
+
+# --- the platform default ----------------------------------------------------
+
+#: The registry backend each platform compiles natively; every other
+#: platform resolves to the ``ref`` oracle.
+PLATFORM_BACKENDS = {"tpu": "pallas-fused"}
+
+
+@functools.lru_cache(maxsize=None)
+def _announce_default(platform: str, kind: str, name: str) -> None:
+    log.info("Platform-default fill backend: %s (platform=%s, "
+             "device_kind=%s)", name, platform, kind)
+
+
+def backend_default() -> str:
+    """The registry backend this platform compiles natively:
+    ``'pallas-fused'`` on TPU, ``'ref'`` elsewhere (CPU CI, where the
+    pallas backends still run, interpreted, when asked for explicitly).
+    Logs the detected ``device_kind`` once per process;
+    ``ExecutionConfig(backend='auto')`` resolves through this."""
+    import jax
+
+    from repro import kernels
+    platform = jax.default_backend()
+    name = PLATFORM_BACKENDS.get(platform, "ref")
+    _announce_default(platform, kernels.device_kind(), name)
+    return name

@@ -7,9 +7,9 @@ carries the orthogonal execution axes the engine composes
 (DESIGN.md §9, §10):
 
   * **backend**  — which fill implementation (`engine.backends` registry:
-                   ``ref`` / ``pallas`` / ``pallas-fused`` / ``pallas-gpu``,
-                   or ``auto`` for the platform default) plus its knobs
-                   (``interpret``, ``tile``, ``block``, ``num_warps``);
+                   ``ref`` / ``pallas`` / ``pallas-fused``, or ``auto``
+                   for the platform default) plus its knobs
+                   (``interpret``, ``tile``);
   * **batching** — how an `IntegrandFamily` workload executes (``vmap`` over
                    the scenario axis vs a ``serial`` per-scenario loop);
   * **sharding** — a device mesh + axis names to shard the fill's global
@@ -217,13 +217,10 @@ class ExecutionConfig:
     the error surfaces exactly once, with the full workload context."""
     backend: str = "ref"            # engine.backends registry name, or
                                     # 'auto' = platform default
-                                    # (kernels.backend_default: pallas-fused
-                                    # on TPU, pallas-gpu on GPU, ref on CPU)
+                                    # (backends.backend_default:
+                                    # pallas-fused on TPU, ref elsewhere)
     interpret: bool | None = None   # pallas mode; None = platform autodetect
     tile: int | None = None         # pallas tile; None = VMEM autotune
-    block: int | None = None        # pallas-gpu evals per program; None =
-                                    # shared-memory autotune (gpu_fill)
-    num_warps: int | None = None    # pallas-gpu Triton compiler knob
     batch: str = "auto"             # family execution: auto | vmap | serial
     mesh: Any = None                # jax Mesh; None = unsharded
     shard_axes: tuple[str, ...] | None = None  # mesh axes to shard fill over
@@ -272,10 +269,6 @@ class ExecutionConfig:
             bits.append(f"interpret={self.interpret}")
         if self.tile is not None:
             bits.append(f"tile={self.tile}")
-        if self.block is not None:
-            bits.append(f"block={self.block}")
-        if self.num_warps is not None:
-            bits.append(f"num_warps={self.num_warps}")
         if self.batch != "auto":
             bits.append(f"batch={self.batch}")
         if self.mesh is not None:

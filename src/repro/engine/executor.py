@@ -284,11 +284,8 @@ def make_single_program(plan: Plan):
     """Build the jitted whole-run program of a single-scenario plan ONCE,
     for callers that run the same plan repeatedly — ``prog(state) ->
     state``.  Unlike the cached program inside :func:`execute` it does not
-    donate its input, so one initial state can be replayed; steady-state
-    timing (``benchmarks/bench_runs.py``, `engine.autotune.calibrate`)
-    needs exactly this — the knob effects the cost model fits are several
-    times smaller than trace+compile, which a fresh-jit-per-call timing
-    would re-pay and drown in."""
+    donate its input, so one initial state can be replayed, timed in steady
+    state, or lowered to read the program's HLO."""
     if plan.is_family or plan.checkpoint is not None:
         raise ValueError("make_single_program builds the single-scenario "
                          "on-device loop; use make_family_program for "

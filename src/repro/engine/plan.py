@@ -92,12 +92,11 @@ def _make_plan(workload, cfg, execution) -> Plan:
     elif execution is not cfg.execution:
         cfg = cfg.with_execution(execution)
     if execution.backend == "auto":
-        # Resolve the platform default (pallas-fused on TPU, pallas-gpu on
-        # GPU, ref elsewhere) BEFORE the autotuner and the capability checks,
-        # so both see the concrete backend and the Plan records it.
-        from repro import kernels
+        # Resolve the platform default (pallas-fused on TPU, ref elsewhere)
+        # BEFORE the autotuner and the capability checks, so both see the
+        # concrete backend and the Plan records it.
         execution = dataclasses.replace(
-            execution, backend=kernels.backend_default())
+            execution, backend=backends_mod.backend_default())
         cfg = cfg.with_execution(execution)
     tuned = None
     if execution.autotune:
@@ -144,10 +143,9 @@ def _make_plan(workload, cfg, execution) -> Plan:
         raise PlanError(
             f"backend {spec.name!r} supports precision pairs [{pairs}], got "
             f"{dtype_name}->{accum_name}")
-    if accum_name != dtype_name and spec.family == "tpu" \
-            and "interpret" in spec.knobs:
+    if accum_name != dtype_name and "interpret" in spec.knobs:
         from repro import kernels
-        if not kernels.resolve_interpret(execution.interpret, spec.family):
+        if not kernels.resolve_interpret(execution.interpret):
             raise PlanError(
                 f"backend {spec.name!r} compiled for TPU cannot accumulate "
                 f"in {accum_name}: Mosaic lowers no float64 (drop the "

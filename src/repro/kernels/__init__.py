@@ -1,21 +1,12 @@
 """Pallas kernel layer: the fill hot-spot the paper optimizes with a custom
-CUDA kernel (vegas_fill.py + gpu_fill.py + ops.py + ref.py) plus the
-platform policy shared by every caller.
+CUDA kernel (vegas_fill.py + ops.py + ref.py) plus the execution-mode
+policy shared by every caller.
 
-Two policies live here:
-
-  * :func:`backend_default` — the platform-default REGISTRY BACKEND:
-    ``'pallas-fused'`` on TPU (the Mosaic-lowered P-V3 kernel),
-    ``'pallas-gpu'`` on GPU (the Triton-lowered scatter kernel),
-    ``'ref'`` everywhere else.  Logs the detected ``device_kind`` once —
-    the same key the cost tables are qualified by (`engine.autotune`).
-  * :func:`resolve_interpret` — the per-kernel-family execution mode.
-    ``interpret=None`` (the default everywhere) autodetects: compiled on
-    the family's native platform (Mosaic on TPU for ``family='tpu'``,
-    Triton on GPU for ``family='gpu'``), the Pallas interpreter elsewhere.
-    Explicit True/False is honored but logged loudly — the historical
-    failure mode was ``interpret=True`` silently running the
-    (orders-of-magnitude slower) interpreter on real accelerators.
+:func:`resolve_interpret` picks the execution mode.  ``interpret=None`` (the
+default everywhere) autodetects: compiled by Mosaic on TPU, the Pallas
+interpreter elsewhere.  Explicit True/False is honored but logged loudly —
+the historical failure mode was ``interpret=True`` silently running the
+(orders-of-magnitude slower) interpreter on a real TPU.
 """
 
 from __future__ import annotations
@@ -27,16 +18,11 @@ import jax
 
 log = logging.getLogger("repro.kernels")
 
-#: Which registry backend each platform compiles natively.
-PLATFORM_BACKENDS = {"tpu": "pallas-fused", "gpu": "pallas-gpu"}
-
-_FAMILY_COMPILER = {"tpu": "Mosaic", "gpu": "Triton"}
-
 
 def device_kind() -> str:
-    """The detected accelerator model (``'cpu'`` / ``'TPU v4'`` /
-    ``'NVIDIA H100 ...'``) — the key BENCH rows and cost-table classes are
-    qualified by, so numbers from different silicon never mix."""
+    """The detected accelerator model (``'cpu'`` / ``'TPU v5 lite'`` ...)
+    — the key cost-table classes are qualified by, so numbers from
+    different silicon never mix."""
     try:
         return jax.devices()[0].device_kind
     except Exception:
@@ -44,52 +30,30 @@ def device_kind() -> str:
 
 
 @functools.lru_cache(maxsize=None)
-def _announce_default(platform: str, kind: str, name: str) -> None:
-    log.info("Platform-default fill backend: %s (platform=%s, "
-             "device_kind=%s)", name, platform, kind)
-
-
-def backend_default() -> str:
-    """The registry backend this platform compiles natively:
-    ``'pallas-fused'`` on TPU, ``'pallas-gpu'`` on GPU, ``'ref'`` elsewhere
-    (CPU CI — where the pallas backends still run, interpreted, when asked
-    for explicitly).  Logs the detected ``device_kind`` once per process;
-    ``ExecutionConfig(backend='auto')`` resolves through this."""
-    platform = jax.default_backend()
-    name = PLATFORM_BACKENDS.get(platform, "ref")
-    _announce_default(platform, device_kind(), name)
-    return name
-
-
-@functools.lru_cache(maxsize=None)
-def _announce(platform: str, family: str, mode: str, source: str) -> None:
-    native = _FAMILY_COMPILER.get(family, "native")
+def _announce(platform: str, mode: str, source: str) -> None:
     msg = (f"Pallas fill mode: {mode.upper()} on platform={platform} "
-           f"[{family} kernel] ({source})")
-    if mode == "interpret" and platform == family:
+           f"({source})")
+    if mode == "interpret" and platform == "tpu":
         log.warning("%s — the interpreter is orders of magnitude slower "
-                    "than compiled %s; pass interpret=None to autodetect",
-                    msg, native)
-    elif mode == "compiled" and platform != family:
-        log.warning("%s — compiled %s lowering is only supported on "
-                    "%s; this will likely fail to lower", msg, native,
-                    family.upper())
+                    "than compiled Mosaic; pass interpret=None to "
+                    "autodetect", msg)
+    elif mode == "compiled" and platform != "tpu":
+        log.warning("%s — compiled Mosaic lowering is only supported on "
+                    "TPU; this will likely fail to lower", msg)
     else:
         log.info("%s", msg)
 
 
-def resolve_interpret(interpret: bool | None, family: str = "tpu") -> bool:
+def resolve_interpret(interpret: bool | None) -> bool:
     """Resolve the tri-state ``interpret`` flag to a concrete bool, logging
-    the choice once per (platform, family, flag) combination.  ``family``
-    names the platform whose compiler lowers this kernel natively
-    (``'tpu'`` for the Mosaic kernels, ``'gpu'`` for the Triton one)."""
+    the choice once per (platform, flag) combination."""
     platform = jax.default_backend()
     if interpret is None:
-        chosen = platform != family
-        _announce(platform, family, "interpret" if chosen else "compiled",
+        chosen = platform != "tpu"
+        _announce(platform, "interpret" if chosen else "compiled",
                   "autodetected, interpret=None")
     else:
         chosen = bool(interpret)
-        _announce(platform, family, "interpret" if chosen else "compiled",
+        _announce(platform, "interpret" if chosen else "compiled",
                   f"explicit interpret={chosen}")
     return chosen

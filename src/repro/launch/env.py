@@ -1,21 +1,18 @@
 """Environment / launch-profile helper: one place for the process-level JAX
 environment knobs the launchers used to set ad hoc via ``os.environ``.
 
-Three idioms (see SNIPPETS.md for their upstream forms):
+Four idioms (see SNIPPETS.md for their upstream forms):
 
   * **precision** — :func:`enable_x64` honors the ``JAX_ENABLE_X64``
     environment variable when no explicit flag is given (f64 accumulation
     runs, e.g. ``--backend ref`` with ``dtype='float64'``);
   * **platform** — :func:`set_platform` pins the JAX platform
-    (cpu/gpu/tpu) before the backend initializes, and can install the
-    documented XLA GPU performance-flag profile (:data:`XLA_GPU_PERF_FLAGS`)
-    for future compiled-GPU rows;
+    (cpu/gpu/tpu) before the backend initializes;
   * **host devices** — :func:`set_host_device_count` forces N host CPU
     devices via ``XLA_FLAGS`` (the multi-device tests' idiom) — it MUST run
     before jax first initializes its backends;
   * **compile cache** — :func:`use_compile_cache` turns on JAX's persistent
-    compilation cache for the CLIs and ``chip_smoke.py`` (never on
-    ``import repro``).
+    compilation cache for the CLIs (never on ``import repro``).
 
 Everything importing jax does so lazily inside the function, so this module
 can be imported (and ``set_host_device_count`` called) before jax is — the
@@ -33,47 +30,6 @@ from pathlib import Path
 #: unset: a fixed directory of the checkout, so a later run finds them again
 #: (the path is part of the cache key; gitignored).
 CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
-
-#: The documented GPU launch profile: every XLA flag the compiled-GPU
-#: benchmark rows run under, with the rationale each flag is there for.
-#: The set follows the published JAX GPU performance guidance (the same
-#: profile SNIPPETS.md's upstream launchers install); the mapping is the
-#: documentation — ``describe_gpu_profile()`` renders it, and the flag
-#: string itself (:data:`XLA_GPU_PERF_FLAGS`) is derived from the keys so
-#: the two can never drift apart.
-GPU_LAUNCH_PROFILE = {
-    "--xla_gpu_enable_triton_softmax_fusion=true":
-        "fuse softmax-shaped reductions through Triton instead of cuDNN "
-        "calls — keeps the fill's normalize/accumulate epilogues in one "
-        "kernel",
-    "--xla_gpu_triton_gemm_any=True":
-        "let Triton codegen any GEMM (not just flagged ones), so the "
-        "one-hot fallbacks lower next to the surrounding fusion rather "
-        "than bouncing to cuBLAS",
-    "--xla_gpu_enable_async_collectives=true":
-        "overlap the sharded fill's cross-device partial-moment reductions "
-        "with compute (the C5 chunk contract makes shards independent "
-        "until the final sum)",
-    "--xla_gpu_enable_latency_hiding_scheduler=true":
-        "schedule HBM loads/collectives ahead of their consumers — the "
-        "fill is bandwidth-bound between kernel launches",
-    "--xla_gpu_enable_highest_priority_async_stream=true":
-        "give the async-collective stream top priority so a small "
-        "all-reduce never waits behind a long fill kernel",
-}
-
-#: Space-joined form of :data:`GPU_LAUNCH_PROFILE` for ``XLA_FLAGS``.
-#: Harmless on CPU/TPU (unknown flags are rejected loudly by XLA only when
-#: a GPU backend consumes them), but only installed on request
-#: (``set_platform(..., gpu_flags=True)`` or ``--gpu-flags``).
-XLA_GPU_PERF_FLAGS = " ".join(GPU_LAUNCH_PROFILE)
-
-
-def describe_gpu_profile() -> str:
-    """Human-readable flag -> rationale table (``--gpu-flags`` + ``--plan``
-    and README's GPU quickstart render this)."""
-    return "\n".join(f"{flag}\n    {why}"
-                     for flag, why in GPU_LAUNCH_PROFILE.items())
 
 _TRUTHY = ("1", "true", "yes", "on")
 
@@ -102,26 +58,15 @@ def enable_x64(enable: bool | None = None) -> bool:
     return bool(enable)
 
 
-def set_platform(platform: str | None = None, *,
-                 gpu_flags: bool = False) -> str | None:
+def set_platform(platform: str | None = None) -> str | None:
     """Pin the JAX platform (``'cpu'``/``'gpu'``/``'tpu'``).  ``None``
     reads ``JAX_PLATFORMS`` / ``JAX_PLATFORM_NAME`` and applies nothing if
-    both are unset.  ``gpu_flags=True`` additionally installs
-    :data:`XLA_GPU_PERF_FLAGS` into ``XLA_FLAGS`` (before backend init
-    only).  Returns the platform applied, or None."""
+    both are unset.  Returns the platform applied, or None."""
     if platform is None:
         platform = (os.environ.get("JAX_PLATFORMS")
                     or os.environ.get("JAX_PLATFORM_NAME"))
         if not platform:
             return None
-    if gpu_flags:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "--xla_gpu_enable_triton_softmax_fusion" not in flags:
-            if _jax_initialized():
-                warnings.warn("XLA GPU flags set after jax initialized its "
-                              "backends — they will not take effect",
-                              RuntimeWarning, stacklevel=2)
-            os.environ["XLA_FLAGS"] = f"{flags} {XLA_GPU_PERF_FLAGS}".strip()
     if _jax_initialized():
         warnings.warn(f"set_platform({platform!r}) after jax initialized "
                       f"its backends — the platform cannot change anymore",
@@ -173,9 +118,6 @@ def add_env_args(ap) -> None:
                     default=None,
                     help="pin the JAX platform (must act before the first "
                          "computation; default: JAX_PLATFORMS/autodetect)")
-    ap.add_argument("--gpu-flags", action="store_true",
-                    help="install the documented XLA GPU performance flag "
-                         "profile (launch.env.XLA_GPU_PERF_FLAGS)")
     ap.add_argument("--host-devices", type=int, default=None, metavar="N",
                     help="force N host CPU devices (XLA_FLAGS; must act "
                          "before jax backend init)")
@@ -186,7 +128,7 @@ def apply_env_args(args) -> None:
     platform first (backend-init-sensitive), x64 last (always safe)."""
     if getattr(args, "host_devices", None):
         set_host_device_count(args.host_devices)
-    if getattr(args, "platform", None) or getattr(args, "gpu_flags", False):
-        set_platform(args.platform, gpu_flags=args.gpu_flags)
+    if getattr(args, "platform", None):
+        set_platform(args.platform)
     if getattr(args, "x64", False) or "JAX_ENABLE_X64" in os.environ:
         enable_x64(True if getattr(args, "x64", False) else None)

@@ -45,22 +45,15 @@ def add_execution_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--backend",
                     choices=sorted(available()) + ["auto"], default="auto",
                     help="fill backend from the engine registry "
-                         "(pallas-fused = P-V3 streaming kernel, pallas-gpu "
-                         "= Triton scatter kernel; auto = platform default "
-                         "via kernels.backend_default)")
+                         "(pallas-fused = P-V3 streaming kernel; auto = "
+                         "platform default via backends.backend_default)")
     ap.add_argument("--interpret", choices=["auto", "true", "false"],
                     default="auto",
-                    help="pallas execution mode; auto = compiled on the "
-                         "kernel's native platform (Mosaic on TPU, Triton "
-                         "on GPU), interpreter elsewhere "
+                    help="pallas execution mode; auto = compiled by "
+                         "Mosaic on TPU, interpreter elsewhere "
                          "(kernels.resolve_interpret)")
     ap.add_argument("--tile", type=int, default=None,
                     help="pallas TPU tile override (default: VMEM autotune)")
-    ap.add_argument("--block", type=int, default=None,
-                    help="pallas-gpu evals per program (default: "
-                         "shared-memory autotune, gpu_fill.autotune_block)")
-    ap.add_argument("--num-warps", type=int, default=None,
-                    help="pallas-gpu Triton num_warps override")
     ap.add_argument("--accum-dtype", choices=["float32", "float64"],
                     default=None,
                     help="accumulation dtype (§15 PrecisionPolicy): widen "
@@ -102,7 +95,7 @@ def add_execution_args(ap: argparse.ArgumentParser) -> None:
 
 
 def build_execution(args, **extra) -> ExecutionConfig:
-    # interpret/tile/block/num_warps are forwarded as given; the plan
+    # interpret/tile are forwarded as given; the plan
     # validator rejects them loudly when the chosen backend declares no
     # such knob.
     interpret = {"auto": None, "true": True, "false": False}[args.interpret]
@@ -120,8 +113,7 @@ def build_execution(args, **extra) -> ExecutionConfig:
     precision = (PrecisionPolicy(accum_dtype=args.accum_dtype)
                  if getattr(args, "accum_dtype", None) else None)
     return ExecutionConfig(backend=args.backend, interpret=interpret,
-                           tile=args.tile, block=args.block,
-                           num_warps=args.num_warps, mesh=mesh, stop=stop,
+                           tile=args.tile, mesh=mesh, stop=stop,
                            grad=grad, autotune=args.autotune,
                            cost_table=args.cost_table, precision=precision,
                            **extra)

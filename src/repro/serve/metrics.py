@@ -3,8 +3,8 @@
 One thread-safe accumulator object per service.  Counters are updated by
 the admission path (submitted/rejected) and the micro-batcher
 (batches/occupancy/cache/latency/billing); :meth:`ServeMetrics.snapshot`
-renders the aggregate view the ``stats()`` endpoint and the load-generator
-benchmark (`benchmarks/bench_serve.py`) consume.
+renders the aggregate view the ``stats()`` endpoint and the
+``repro.launch.serve --stats-json`` output report.
 """
 
 from __future__ import annotations

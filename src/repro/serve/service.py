@@ -30,7 +30,7 @@ composed entirely from engine pieces PRs 1–6 built:
     iterations saved, exposed by :meth:`SweepService.stats`.
 
 The service is in-process: drive it synchronously with :meth:`drain`
-(tests, benchmarks) or start the background worker thread
+(tests) or start the background worker thread
 (:meth:`start`/:meth:`stop`) that gathers each burst for ``max_wait_s``
 and executes it — the long-lived form the `repro.launch.serve` CLI runs.
 """

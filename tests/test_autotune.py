@@ -1,10 +1,9 @@
-"""Plan autotuner (ISSUE 8, DESIGN.md §13): cost-model fitting, table
-persistence/resolution, the knob chooser through ``make_plan(autotune=True)``,
-the serving layer's shared cost model, and the --gate-run pairing logic.
+"""Plan autotuner (DESIGN.md §13): cost-model fitting, table
+persistence/resolution, the knob chooser through ``make_plan(autotune=True)``
+and the serving layer's shared cost model.
 
 The calibration RUNNER (steady-state timing over the measurement grid) is
-exercised ref-only here to keep the suite fast; the full grid is CI's
-autotune-smoke job (benchmarks/bench_calibrate.py)."""
+exercised ref-only here to keep the suite fast."""
 
 import dataclasses
 
@@ -108,8 +107,7 @@ def test_resolve_table_priority(tmp_path, monkeypatch):
 
 def test_tune_reduces_ncap_padding_on_high_dim_shape():
     # roos_arnold d=10, neval=1e5: n_cubes=1024 so n_cap=102048; the default
-    # chunk 16384 rounds n_cap up 12.4%, chunk 8192 only 4.4% — the measured
-    # win this PR is built on (BENCH_run.json run/autotune/* rows).
+    # chunk 16384 rounds n_cap up 12.4%, chunk 8192 only 4.4%.
     ig = make_roos_arnold()
     cfg = VegasConfig(neval=100_000, max_it=6, chunk=16_384,
                       execution=ExecutionConfig(autotune=True))
@@ -275,78 +273,6 @@ def test_serve_consumes_table_as_budget_prior(tmp_path):
         r = t.result(timeout=120)
     assert r.capped
     assert int(r.n_it_used[0]) < 8
-
-
-# --- the benchmark gate ------------------------------------------------------
-
-def _row(name, us, interpret=None, chunk=None):
-    return {"name": name, "us_per_call": us, "interpret": interpret,
-            "chunk": chunk}
-
-
-def test_gate_run_pairing():
-    from benchmarks.run import gate_run
-    ok = [_row("run/autotune/a/default", 100.0),
-          _row("run/autotune/a/autotuned", 80.0)]
-    assert gate_run(ok) == []
-    # within the 5% noise allowance but never faster anywhere -> one failure
-    noise = [_row("run/autotune/a/default", 100.0),
-             _row("run/autotune/a/autotuned", 104.0)]
-    assert any("won on none" in f for f in gate_run(noise))
-    # slower beyond tolerance -> named failure
-    slow = ok + [_row("run/autotune/b/default", 100.0),
-                 _row("run/autotune/b/autotuned", 120.0)]
-    assert any("run/autotune/b" in f for f in gate_run(slow))
-    # cross-mode pairs are skipped, and a gate with nothing measured fails
-    cross = [_row("run/autotune/a/default", 100.0, interpret=True),
-             _row("run/autotune/a/autotuned", 500.0, interpret=False)]
-    assert any("nothing to check" in f for f in gate_run(cross))
-    assert any("nothing to check" in f for f in gate_run([]))
-    # unrelated run/* rows never pair
-    assert any("nothing to check" in f
-               for f in gate_run([_row("run/roos_arnold/ref", 50.0)]))
-
-
-def test_gate_abs_pairing():
-    from benchmarks.run import gate_abs
-
-    def row(name, us, dk, backend="pallas_gpu", interpret=False):
-        return {"name": name, "us_per_call": us, "device_kind": dk,
-                "backend": backend, "interpret": interpret}
-
-    a100 = "NVIDIA A100-SXM4-40GB"
-    prior = [row("f/x", 100.0, a100), row("f/x", 90.0, a100),  # best = 90
-             row("f/y", 100.0, None)]                          # legacy row
-    # within threshold vs the BEST prior -> checked, no failure
-    fails, checked, skipped = gate_abs([row("f/x", 98.0, a100)], prior)
-    assert (fails, checked, skipped) == ([], 1, 0)
-    # regression beyond 1.10x -> named failure with the ratio
-    fails, checked, _ = gate_abs([row("f/x", 120.0, a100)], prior)
-    assert checked == 1 and any("1.33x" in f and "f/x" in f for f in fails)
-    # a legacy (unstamped) prior matches any REAL device_kind
-    fails, checked, _ = gate_abs([row("f/y", 99.0, a100)], prior)
-    assert (fails, checked) == ([], 1)
-    # generic-cpu rows and no-prior rows are skipped, never failed
-    fails, checked, skipped = gate_abs(
-        [row("f/x", 500.0, "cpu"), row("f/x", 500.0, None),
-         row("f/new", 500.0, a100)], prior)
-    assert (fails, checked, skipped) == ([], 0, 3)
-    # interpret mode is part of the pairing key
-    fails, checked, skipped = gate_abs(
-        [row("f/x", 500.0, a100, interpret=True)], prior)
-    assert (fails, checked, skipped) == ([], 0, 1)
-
-
-def test_emit_rows_carry_device_kind():
-    from benchmarks import common
-    common.reset_rows()
-    try:
-        common.emit("x/y", 1e-3, backend="ref", chunk=128)
-        row = common.ROWS[-1]
-        assert row["device_kind"] == jax.devices()[0].device_kind
-        assert row["chunk"] == 128
-    finally:
-        common.reset_rows()
 
 
 # --- steady-state program reuse ----------------------------------------------

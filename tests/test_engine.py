@@ -141,20 +141,44 @@ def test_plan_rejects_unknown_backend():
         E.make_plan(IG, FAST, execution=E.ExecutionConfig(backend="cuda"))
 
 
-def test_plan_rejects_knobs_the_backend_does_not_declare():
-    with pytest.raises(E.PlanError, match="tile.*not a knob.*'ref'"):
-        E.make_plan(IG, FAST, execution=E.ExecutionConfig(tile=128))
-    with pytest.raises(E.PlanError, match="interpret.*not a knob"):
-        E.make_plan(IG, FAST, execution=E.ExecutionConfig(interpret=True))
+@pytest.mark.parametrize("knob", [dict(tile=128), dict(interpret=True)],
+                         ids=["tile", "interpret"])
+def test_plan_rejects_knobs_the_backend_does_not_declare(knob):
+    (name,) = knob
+    with pytest.raises(E.PlanError, match=f"{name}.*not a knob.*'ref'"):
+        E.make_plan(IG, FAST, execution=E.ExecutionConfig(**knob))
 
 
-def test_plan_rejects_unsupported_dtype():
+@pytest.mark.parametrize("backend", ["pallas", "pallas-fused"])
+def test_plan_rejects_unsupported_dtype(backend):
     cfg = dataclasses.replace(FAST, dtype="float64")
-    with pytest.raises(E.PlanError, match="float32.*float64"):
-        E.make_plan(IG, cfg,
-                    execution=E.ExecutionConfig(backend="pallas-fused"))
+    with pytest.raises(E.PlanError, match="float32.*float64") as ei:
+        E.make_plan(IG, cfg, execution=E.ExecutionConfig(backend=backend))
+    # the reason is named only where the RNG runs inside the kernel
+    assert ("in-kernel RNG" in str(ei.value)) == (backend == "pallas-fused")
     # the oracle declares f64 support: same plan, no error
     E.make_plan(IG, cfg, execution=E.ExecutionConfig(backend="ref"))
+
+
+@pytest.mark.parametrize("mode", ["pathwise", "score"])
+def test_plan_rejects_grad(mode):
+    # pallas-fused declares neither grad capability
+    with pytest.raises(E.PlanError, match=f"grad-{mode}"):
+        E.make_plan(IG, FAST, execution=E.ExecutionConfig(
+            backend="pallas-fused", grad=E.GradPolicy(mode=mode)))
+
+
+def test_plan_allows_vmap_shard_stop_and_knobs():
+    fam = make_gaussian_family(np.array([0.3, 0.7]))
+    plan = E.make_plan(fam, FAST, execution=E.ExecutionConfig(
+        backend="pallas-fused", batch="vmap", tile=64,
+        stop=E.StopPolicy(rtol=0.1)))
+    assert plan.batched and plan.backend.name == "pallas-fused"
+    assert plan.stop is not None and plan.execution.tile == 64
+    plan = E.make_plan(IG, FAST, execution=E.ExecutionConfig(
+        backend="pallas-fused", tile=64, mesh=make_local_mesh()))
+    assert plan.backend.supports("shardable")
+    assert plan.n_shards == jax.device_count()
 
 
 def test_plan_rejects_vmap_of_a_plain_integrand():
@@ -279,14 +303,34 @@ def test_run_batch_through_engine_matches_serial():
 # --- entry-point defaults and the compile cache -----------------------------
 
 def test_cli_and_requests_default_to_the_platform_backend(monkeypatch):
-    from repro import kernels
+    from repro.engine import backends
     from repro.launch import env
     from repro.launch.integrate import main
     from repro.serve import IntegrationRequest
     monkeypatch.setattr(env, "use_compile_cache", lambda: None)
     assert IntegrationRequest(family="gaussian", params=[0.5]).backend == "auto"
     plan = main(["--integrand", "gaussian", "--neval", "1000", "--plan"])
-    assert plan.backend.name == kernels.backend_default()
+    assert plan.backend.name == backends.backend_default()
+
+
+def test_backend_default_registry_names():
+    from repro.engine import backends
+    assert backends.PLATFORM_BACKENDS == {"tpu": "pallas-fused"}
+    assert backends.backend_default() == backends.PLATFORM_BACKENDS.get(
+        jax.default_backend(), "ref")
+    assert E.available() == ("pallas", "pallas-fused", "ref")
+
+
+def test_auto_backend_resolves_in_plan():
+    from repro.engine import backends
+    plan = E.make_plan(IG, FAST, execution=E.ExecutionConfig(backend="auto"))
+    assert plan.backend.name == backends.backend_default()
+    assert plan.execution.backend == plan.backend.name  # recorded, not 'auto'
+    # auto + autotune: the tuner sees the concrete backend
+    plan = E.make_plan(IG, FAST, execution=E.ExecutionConfig(
+        backend="auto", autotune=True))
+    assert plan.tuned is not None
+    assert plan.backend.name == backends.backend_default()
 
 
 def test_use_compile_cache_leaves_a_set_directory_to_jax(monkeypatch):

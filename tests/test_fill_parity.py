@@ -1,6 +1,5 @@
-"""Three-way backend parity sweep: ``fill_pallas`` (interpret mode, both
-the P-V2 baseline and the P-V3 fused streaming kernel) AND the Triton-
-structured ``fill_pallas_gpu`` scatter kernel vs ``fill_reference`` across
+"""Backend parity sweep: ``fill_pallas`` (interpret mode, both the P-V2
+baseline and the P-V3 fused streaming kernel) vs ``fill_reference`` across
 dimensions, stratification counts, and non-power-of-two chunk/tile shapes.
 
 All paths share the chunk-keyed RNG contract (DESIGN.md C5) — the in-kernel
@@ -57,24 +56,6 @@ def _assert_fill_parity(dim, nstrat, chunk, n_chunks, tile, ninc=32,
                 b, a, rtol=1e-4, atol=1e-5 * scale,
                 err_msg=f"{field} fused={fused} rng_in_kernel={rng} dim={dim} "
                         f"nstrat={nstrat} chunk={chunk} tile={tile}")
-    # The GPU scatter kernel rides the same sweep: hybrid (host uniforms)
-    # and in-kernel RNG (the compiled-Triton program, run interpreted).
-    # block=tile reuses each case's deliberately awkward step size; the
-    # wrapper's divisor fallback (_pick_block) absorbs non-divisors.
-    for rng in (None, True):
-        gpu = fill_mod.fill_pallas_gpu(edges, n_h, key, _ig, nstrat=nstrat,
-                                       n_cap=n_cap, chunk=chunk,
-                                       interpret=True, block=tile,
-                                       rng_in_kernel=rng)
-        for field in ("map_sums", "map_counts", "cube_s1", "cube_s2"):
-            a = np.asarray(getattr(ref, field))
-            b = np.asarray(getattr(gpu, field))
-            scale = np.abs(a).max() or 1.0
-            np.testing.assert_allclose(
-                b, a, rtol=1e-4, atol=1e-5 * scale,
-                err_msg=f"{field} backend=pallas-gpu rng_in_kernel={rng} "
-                        f"dim={dim} nstrat={nstrat} chunk={chunk} "
-                        f"block<={tile}")
 
 
 @pytest.mark.parametrize("dim", [1, 2, 4])

@@ -12,7 +12,7 @@ floating point), which silently zeroes the very drift being measured.
 
 Expected ordering differs by where the backend widens (§15):
 
-* ref / pallas-gpu widen the weights BEFORE the within-chunk sums, so
+* ref widens the weights BEFORE the within-chunk sums, so
   f32 > Kahan > widened, and the widened error is exactly 0 here.
 * pallas-fused keeps products AND the per-tile one-hot matmul in f32 for
   the MXU and widens the per-tile partial sums after — so Kahan and
@@ -97,13 +97,6 @@ def test_error_ordering_ref(x64):
     assert e["wide"] == 0.0, e
 
 
-def test_error_ordering_gpu_interpret(x64):
-    e = _planted_errors(fill_mod.fill_pallas_gpu, neval=4 * 32749, chunk=128,
-                        interpret=True)
-    assert e["f32"] > e["kahan"] > e["wide"], e
-    assert e["wide"] == 0.0, e
-
-
 def test_error_ordering_fused_interpret(x64):
     # Power-of-2 neval here: per-chunk partials repeat identically, making
     # the within-chunk floor shared by Kahan and widening bit-identical.
@@ -137,8 +130,7 @@ def test_widened_fill_result_dtype(x64):
     default policy still returns f32 (no silent promotion)."""
     for fn, extra in [(fill_mod.fill_reference, {}),
                       (fill_mod.fill_pallas,
-                       dict(interpret=True, fused_cubes=True)),
-                      (fill_mod.fill_pallas_gpu, dict(interpret=True))]:
+                       dict(interpret=True, fused_cubes=True))]:
         edges = vmap_.uniform_edges(np.zeros(D), np.ones(D), NINC,
                                     jnp.float32)
         n_h = strat.uniform_nh(1024, N_CUBES)
@@ -179,7 +171,7 @@ def _cfg(backend, accum=None, sample=None, dtype="float32", **exec_kw):
 
 def test_plan_rejects_f64_samples_on_kernel_backends():
     with _x64(True):
-        for backend in ("pallas", "pallas-fused", "pallas-gpu"):
+        for backend in ("pallas", "pallas-fused"):
             with pytest.raises(E.PlanError, match="supports dtypes"):
                 E.make_plan(make_cosine(dim=2), _cfg(backend,
                                                      dtype="float64"))
@@ -215,7 +207,7 @@ def test_plan_rejects_grad_with_widened_accum(x64):
 
 def test_plan_accepts_widened_and_narrowed_policies(x64):
     # f32 samples -> f64 accumulators on every kernel backend.
-    for backend in ("ref", "pallas", "pallas-fused", "pallas-gpu"):
+    for backend in ("ref", "pallas", "pallas-fused"):
         kw = {} if backend == "ref" else dict(interpret=True)
         plan = E.make_plan(make_cosine(dim=2),
                            _cfg(backend, accum="float64", **kw))
@@ -229,10 +221,10 @@ def test_plan_accepts_widened_and_narrowed_policies(x64):
 
 
 def test_widened_plan_end_to_end(x64):
-    """ISSUE 10 acceptance: pallas-fused and pallas-gpu accept and execute
-    accum_dtype=float64 plans (interpret mode); estimates stay sane."""
+    """pallas-fused accepts and executes accum_dtype=float64 plans
+    (interpret mode); estimates stay sane."""
     ig = make_cosine(dim=2)
-    for backend in ("ref", "pallas-fused", "pallas-gpu"):
+    for backend in ("ref", "pallas-fused"):
         kw = {} if backend == "ref" else dict(interpret=True)
         plan = E.make_plan(ig, _cfg(backend, accum="float64", **kw))
         res = E.execute(plan, key=jax.random.PRNGKey(3))
@@ -261,15 +253,6 @@ def test_valid_tiles_shrink_under_f64_accum():
     t64 = valid_tiles(**kw, accum_itemsize=8)
     assert set(t64) < set(t32), (t32, t64)
     assert max(t64) < max(t32), (t32, t64)
-
-
-def test_valid_blocks_shrink_under_f64_accum():
-    from repro.kernels.gpu_fill import valid_blocks
-    kw = dict(chunk=4096, d=4, ninc=1024)
-    b32 = valid_blocks(**kw, accum_itemsize=4)
-    b64 = valid_blocks(**kw, accum_itemsize=8)
-    assert set(b64) < set(b32), (b32, b64)
-    assert max(b64) < max(b32), (b32, b64)
 
 
 def test_autotune_prices_accum_itemsize():
@@ -391,44 +374,3 @@ def test_checkpoint_restore_rejects_kind_mismatch(tmp_path):
     with pytest.raises(ValueError, match="different kinds") as ei:
         CK.restore(p, like)
     assert "'b'" in str(ei.value) or "b" in str(ei.value)
-
-
-def test_bench_gates_never_pair_across_precision_policies():
-    """Regression guard for --gate-abs/--gate-run/--gate-fill: a widened-f64
-    timing must never be compared against an f32 (or legacy, un-stamped)
-    timing."""
-    from benchmarks.run import gate_abs, gate_fill, gate_run
-
-    def row(name, us, accum=None, **kw):
-        r = dict(name=name, us_per_call=us, interpret=False, **kw)
-        if accum is not None:
-            r["accum_dtype"] = accum
-        return r
-
-    # gate_fill: a slower f64 fused row is SKIPPED against its f32 twin...
-    rows = [row("d4/fill_pallas", 100.0),
-            row("d4/fill_fused", 900.0, accum="float64")]
-    assert gate_fill(rows) == []
-    # ...but fails once both rows share the policy.
-    rows[1] = row("d4/fill_fused", 900.0)
-    assert gate_fill(rows) != []
-
-    # gate_run: mismatched policies leave no measurable pair.
-    rows = [row("run/autotune/s/default", 100.0),
-            row("run/autotune/s/autotuned", 900.0, accum="float64")]
-    fails = gate_run(rows)
-    assert any("nothing to check" in f for f in fails), fails
-
-    # gate_abs: a legacy prior (no accum_dtype stamp => f32) never gates a
-    # widened current row — skipped, not failed.
-    cur = [row("fill/x", 1000.0, accum="float64", backend="pallas-fused",
-               device_kind="tpu-v4")]
-    prior = [row("fill/x", 100.0, backend="pallas-fused",
-                 device_kind="tpu-v4")]
-    fails, checked, skipped = gate_abs(cur, prior)
-    assert fails == [] and checked == 0 and skipped == 1
-    # Same row stamped f32 pairs normally and trips the gate.
-    cur[0] = row("fill/x", 1000.0, backend="pallas-fused",
-                 device_kind="tpu-v4")
-    fails, checked, _ = gate_abs(cur, prior)
-    assert checked == 1 and fails != []

@@ -75,11 +75,8 @@ def _check_pulls(pulls, label):
 # --- pull-distribution coverage, one family per paper workload class ---------
 
 def test_pull_coverage_gaussian_peak():
-    # Same configuration as the CI PULLS.json artifact, by construction:
-    # the artifact visualizes exactly the distribution asserted here.
-    from benchmarks.bench_runs import PULL_CFG_KW, PULL_FAMILY_KW
-    fam = make_gaussian_family(np.full(N_RUNS, 0.5), **PULL_FAMILY_KW)
-    cfg = VegasConfig(**PULL_CFG_KW)
+    fam = make_gaussian_family(np.full(N_RUNS, 0.5), dim=3, sigma=0.2)
+    cfg = VegasConfig(neval=6_000, max_it=10, skip=5, ninc=64, chunk=2048)
     pulls, res = _pulls(fam, cfg)
     _check_pulls(np.asarray(pulls), "gaussian_peak")
     assert 0.3 <= float(np.mean(res.chi2_dof)) <= 3.0, res.chi2_dof
