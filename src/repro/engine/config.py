@@ -61,7 +61,7 @@ class PrecisionPolicy:
     ``sample_dtype=None`` inherits the algorithm config's own ``dtype`` and
     ``accum_dtype=None`` matches the sample dtype (the classic single-dtype
     run).  The interesting split is ``f32 -> f64``: products stay f32 — on
-    the fused TPU kernel they must, the MXU contracts f32 and the in-kernel
+    the fused TPU kernel they must, the MXU sums in f32 and the in-kernel
     RNG reproduces the f32 uniform bit pattern — but every running sum is
     widened to f64 before accumulation, cuVegas' own double-precision
     accumulator design.

@@ -60,11 +60,13 @@ def key_bits(key) -> jax.Array:
 #: no other axis of a realistic integrand matches it by accident.
 _PROBE_ROWS = 257
 _SUBLANE = 8
-#: VMEM bytes per element of a one-hot operand: the f32 one-hot plus the
-#: bf16 pieces a full-precision (HIGHEST) MXU contraction splits it into.
-#: Mosaic's scoped allocation on a TPU v5e comes to 16-17 bytes per one-hot
-#: element across d = 2..16 at ninc 1024.
-_ONEHOT_BYTES = 16
+#: VMEM bytes per element of a one-hot operand: the bf16 one-hot that one
+#: MXU pass contracts (vegas_fill._onehot_dot) and Mosaic's scratch around
+#: it.  Mosaic's scoped allocation for the fused kernel, compiled for a TPU
+#: v5e, grows by 2.7-4.0 bytes per one-hot element across d = 2..16 and
+#: tiles 32..256 at ninc 1024 (16.4-17.6 when the contractions ran as
+#: six-pass f32 matmuls at Precision.HIGHEST).
+_ONEHOT_BYTES = 4
 
 
 def _round_up(n: int, m: int) -> int:
@@ -118,12 +120,14 @@ def tile_footprint_bytes(tile: int, d: int, ninc: int, n_cubes: int, *,
                          accum_itemsize: int = 4, row_bytes: int = 0) -> int:
     """VMEM footprint of one kernel tile under the DESIGN.md §7/§15 budget
     math: the d pass-1 one-hots stay live for pass-2 reuse (d * tile * ninc
-    elements at ``_ONEHOT_BYTES`` — products feed the MXU in the sample
-    dtype, at full f32 precision), the cube-window one-hot adds tile * span
-    more, the transform scratch ~8 f32 copies of (tile, d),
-    the integrand's own intermediates (``row_bytes`` a row, see
-    :func:`eval_row_bytes`), plus the grid-resident state — the f32 map
-    tables (2 * d * ninc) and the ACCUMULATORS at ``accum_itemsize`` bytes
+    elements at ``_ONEHOT_BYTES`` — bf16, each contraction one exact MXU
+    pass), the cube-window one-hot adds tile * span more, the transform
+    scratch ~8 f32 copies of (tile, d), the integrand's own intermediates
+    (``row_bytes`` a row, see :func:`eval_row_bytes`), plus the
+    grid-resident state — the map table at 8 bytes a (d, ninc) entry (the
+    two f32 tables it is split from; the allocation ``_ONEHOT_BYTES`` was
+    fit to includes its six bf16 pieces) and the ACCUMULATORS at
+    ``accum_itemsize`` bytes
     apiece: the (d, ninc) ms/mc histogram pair and the two (rows, LANE)
     cube-moment tiles (~2.1 MB f32 / ~4.2 MB f64 at the max_cubes = 2^18
     cap).  Widened f64 accumulation therefore shrinks the budget available
@@ -210,7 +214,7 @@ def fill(edges, n_h, key, integrand, *, nstrat: int, n_cap: int, chunk: int,
     bit-identical either way, see ``vegas_fill.vegas_fill_fused``.
 
     ``accum_dtype`` (default: ``dtype``) widens every moment accumulator
-    (§15): products stay f32 for the MXU, but the fused kernel's VMEM
+    (§15): products and the MXU's sums stay f32, but the fused kernel's VMEM
     accumulator tiles — and the baseline path's XLA scatter-adds — carry the
     wider dtype, and the returned FillResult comes back in it.
     ``return_comp=True`` (with ``kahan=True``) returns the (sums,
@@ -236,11 +240,12 @@ def fill(edges, n_h, key, integrand, *, nstrat: int, n_cap: int, chunk: int,
     tile = _pick_tile(tile, chunk, d, ninc, n_cubes, accum.itemsize,
                       eval_row_bytes(integrand, d, dtype) if tile is None
                       else 0)
-    if fused_cubes and dtype != jnp.float32:
+    if dtype != jnp.float32:
         raise ValueError(
-            f"fused_cubes=True is f32-only samples (the in-kernel RNG "
-            f"reproduces the f32 uniform bit pattern; widen accum_dtype "
-            f"instead, §15); got dtype={dtype}")
+            f"the fill kernels are f32-only samples (the in-kernel RNG "
+            f"reproduces the f32 uniform bit pattern, and the one-hot "
+            f"contractions split f32 exactly into bf16 pieces; widen "
+            f"accum_dtype instead, §15); got dtype={dtype}")
     if accum not in (jnp.float32, jnp.float64):
         raise ValueError(f"accum_dtype must be float32 or float64, "
                          f"got {accum}")

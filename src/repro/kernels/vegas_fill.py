@@ -22,8 +22,13 @@ TPU adaptation of the CUDA design (DESIGN.md D1-D4):
     Pallas grid is sequential on TPU, so ``ms_ref[...] +=`` across tiles is
     race-free by construction — no atomics exist and none are needed.
   * The same one-hot matrix implements the edge/width *gathers* (table
-    lookups as (tile, ninc) @ (ninc, 1) matvecs) — random HBM access in the
+    lookups as (tile, ninc) @ (ninc, 6) matvecs) — random HBM access in the
     CUDA kernel becomes dense VMEM-resident MXU work.
+  * Every contraction is ONE bf16 MXU pass and exact (``_onehot_dot``): the
+    one-hot is 0/1, exact in bf16, and each f32 operand goes in as three
+    bf16 pieces (``_split3``, 8 + 8 + 8 significand bits) added back in f32
+    as ``(hi + mid) + lo``.  A gather returns the table entry bit for bit;
+    a histogram or cube moment is an f32 sum of exact products.
   * The Jacobian is accumulated in log space (overflow-safe for adapted
     high-d maps).
   * The integrand is a traced JAX callable inlined into the kernel body — the
@@ -32,8 +37,8 @@ TPU adaptation of the CUDA design (DESIGN.md D1-D4):
 Block layout per grid step i (grid = n // tile):
   u      (tile, d)   VMEM   uniforms for this tile
   cube   (tile, 1)   VMEM   int32 hypercube ids (n_cubes == masked)
-  edges  (d, ninc)   VMEM   left interval edges (replicated across steps)
-  widths (d, ninc)   VMEM   interval widths     (replicated across steps)
+  table  (6d, ninc)  VMEM   bf16 pieces of the left interval edges and
+                            widths (``_split_table``; replicated across steps)
   w      (tile, 1)   VMEM   per-eval J*f output
   ms/mc  (d, ninc)   VMEM   accumulated across the sequential grid
 """
@@ -47,14 +52,74 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _TINY = 1e-30
-#: Every f32 contraction in these kernels is a one-hot gather or a one-hot
-#: histogram, and must come back exact.  Mosaic's default f32 matmul on
-#: the MXU rounds its operands to bf16 (a gather off by ~3.5e-3 relative on
-#: a TPU v5e); HIGHEST runs it at full f32.
-_EXACT = jax.lax.Precision.HIGHEST
+_HI16 = 0xFFFF0000
 
 
-def _fill_kernel(u_ref, cube_ref, edges_ref, widths_ref, *rest,
+def _split3(t):
+    """``t`` (f32) as three f32 pieces, each exact in bf16, whose sum
+    ``(hi + mid) + lo`` is ``t`` bit for bit: the top 8 significand bits,
+    the next 8 and the last 8.  Each cut masks the low 16 bits off (a
+    truncation), so both subtractions are exact; a bf16 round trip would do
+    the same, but a compiler allowed excess precision may fold it away.
+    Exact for every ``|t| >= 2**-102`` (about 2e-31), also where subnormal
+    results flush to zero, as on the TPU (checked over every f32)."""
+    def top8(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32) & jnp.uint32(_HI16)
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+    hi = top8(t)
+    r = t - hi
+    mid = top8(r)
+    return hi, mid, r - mid
+
+
+def _onehot_dot(lhs, rhs, contract):
+    """The kernels' one contraction idiom (DESIGN.md D1/D2): a bf16 one-hot
+    against bf16 pieces of f32 values (:func:`_split3`), one DEFAULT-precision
+    MXU pass with f32 accumulation.  Both operands are exact in bf16, so every
+    product is exact and the sums are f32 sums; the caller adds the pieces
+    back as ``(hi + mid) + lo``."""
+    return jax.lax.dot_general(
+        lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16),
+        (contract, ((), ())), preferred_element_type=jnp.float32)
+
+
+def _split_table(edges_lo, widths):
+    """The (6d, ninc) bf16 map table both kernels gather from: rows 6k..6k+5
+    are the hi/mid/lo pieces of ``edges_lo[k]`` then of ``widths[k]``.  Split
+    once per call, outside the grid."""
+    d, ninc = edges_lo.shape
+    pieces = jnp.stack([*_split3(edges_lo), *_split3(widths)], axis=1)
+    return pieces.reshape(6 * d, ninc).astype(jnp.bfloat16)
+
+
+def _gather_map(oh, table_ref, k: int):
+    """(e_lo, dx), each (tile, 1) f32 and bit-exact, for dimension ``k``:
+    one matvec of the (tile, ninc) one-hot against the six table rows."""
+    g = _onehot_dot(oh, table_ref[6 * k:6 * k + 6, :], ((1,), (1,)))
+    return ((g[:, 0:1] + g[:, 1:2]) + g[:, 2:3],
+            (g[:, 3:4] + g[:, 4:5]) + g[:, 5:6])
+
+
+def _stack(split=(), exact=()):
+    """The (tile, 3 * len(split) + len(exact)) bf16 operand of
+    :func:`_onehot_sums`: the :func:`_split3` pieces of each ``split``
+    column, then the ``exact`` columns (0/1 counts, already exact in bf16)."""
+    cols = [p for c in split for p in _split3(c)] + list(exact)
+    return jnp.concatenate(cols, axis=1).astype(jnp.bfloat16)
+
+
+def _onehot_sums(lhs, oh, n_split: int):
+    """Per one-hot slot, the sum over the tile of each column behind ``lhs``
+    (:func:`_stack`), as (1, n) f32 rows in the same order, from one pass:
+    each split column is rebuilt from its pieces' sums as
+    ``(hi + mid) + lo``."""
+    m = _onehot_dot(lhs, oh, ((0,), (0,)))                      # (cols, n)
+    rows = [(m[3 * j:3 * j + 1] + m[3 * j + 1:3 * j + 2])
+            + m[3 * j + 2:3 * j + 3] for j in range(n_split)]
+    return rows + [m[j:j + 1] for j in range(3 * n_split, m.shape[0])]
+
+
+def _fill_kernel(u_ref, cube_ref, table_ref, *rest,
                  nstrat: int, n_cubes: int, ninc: int, integrand):
     *const_refs, w_ref, ms_ref, mc_ref = rest
     i = pl.program_id(0)
@@ -78,13 +143,8 @@ def _fill_kernel(u_ref, cube_ref, edges_ref, widths_ref, *rest,
         yn = y_k * ninc
         iy_k = jnp.clip(yn.astype(jnp.int32), 0, ninc - 1)      # (tile, 1)
         frac = yn - iy_k.astype(dtype)
-        oh = (iy_k == lanes).astype(dtype)                      # (tile, ninc)
-        e_lo = jax.lax.dot_general(
-            oh, edges_ref[k:k + 1, :], (((1,), (1,)), ((), ())),
-            precision=_EXACT, preferred_element_type=dtype)  # (tile, 1)
-        dx = jax.lax.dot_general(
-            oh, widths_ref[k:k + 1, :], (((1,), (1,)), ((), ())),
-            precision=_EXACT, preferred_element_type=dtype)  # (tile, 1)
+        oh = (iy_k == lanes).astype(jnp.bfloat16)               # (tile, ninc)
+        e_lo, dx = _gather_map(oh, table_ref, k)                # (tile, 1) x2
         x_cols.append(e_lo + frac * dx)
         iy_cols.append(iy_k)
         logjac = logjac + jnp.log(jnp.maximum(ninc * dx, _TINY))
@@ -98,8 +158,7 @@ def _fill_kernel(u_ref, cube_ref, edges_ref, widths_ref, *rest,
     fx = fx.reshape(tile, 1).astype(dtype)
     w = jnp.where(valid, jac * fx, jnp.zeros((), dtype))        # (tile, 1)
     w_ref[...] = w
-    w2 = w * w
-    cnt = valid.astype(dtype)
+    w2cnt = _stack(split=(w * w,), exact=(valid.astype(dtype),))  # (tile, 4)
 
     # ---- pass 2: map-histogram accumulation (MXU one-hot contractions) ----
     @pl.when(i == 0)
@@ -108,13 +167,8 @@ def _fill_kernel(u_ref, cube_ref, edges_ref, widths_ref, *rest,
         mc_ref[...] = jnp.zeros_like(mc_ref)
 
     for k in range(d):
-        oh = (iy_cols[k] == lanes).astype(dtype)                # (tile, ninc)
-        ms_k = jax.lax.dot_general(
-            w2, oh, (((0,), (0,)), ((), ())),
-            precision=_EXACT, preferred_element_type=dtype)  # (1, ninc)
-        mc_k = jax.lax.dot_general(
-            cnt, oh, (((0,), (0,)), ((), ())),
-            precision=_EXACT, preferred_element_type=dtype)  # (1, ninc)
+        oh = (iy_cols[k] == lanes).astype(jnp.bfloat16)         # (tile, ninc)
+        ms_k, mc_k = _onehot_sums(w2cnt, oh, 1)
         ms_ref[k:k + 1, :] += ms_k
         mc_ref[k:k + 1, :] += mc_k
 
@@ -152,6 +206,7 @@ def vegas_fill(u, cube, edges_lo, widths, *, nstrat: int, n_cubes: int,
     ninc = edges_lo.shape[1]
     assert n % tile == 0, (n, tile)
     dtype = u.dtype
+    assert dtype == jnp.float32, "the bf16 split is exact for f32 only"
     kig, flat_consts, const_specs = _const_transport(integrand, ig_consts)
 
     kernel = functools.partial(_fill_kernel, nstrat=nstrat, n_cubes=n_cubes,
@@ -163,8 +218,7 @@ def vegas_fill(u, cube, edges_lo, widths, *, nstrat: int, n_cubes: int,
         in_specs=[
             pl.BlockSpec((tile, d), lambda i: (i, 0)),      # u
             pl.BlockSpec((tile, 1), lambda i: (i, 0)),      # cube
-            pl.BlockSpec((d, ninc), lambda i: (0, 0)),      # edges_lo
-            pl.BlockSpec((d, ninc), lambda i: (0, 0)),      # widths
+            pl.BlockSpec((6 * d, ninc), lambda i: (0, 0)),  # split map table
             *const_specs,                                   # integrand consts
         ],
         out_specs=[
@@ -179,7 +233,7 @@ def vegas_fill(u, cube, edges_lo, widths, *, nstrat: int, n_cubes: int,
         ],
         interpret=interpret,
         name="vegas_fill",
-    )(u, cube, edges_lo, widths, *flat_consts)
+    )(u, cube, _split_table(edges_lo, widths), *flat_consts)
 
 
 # ---------------------------------------------------------------------------
@@ -301,7 +355,7 @@ def padded_cube_rows(n_cubes: int, tile: int) -> int:
 def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
                        chunk: int, tile: int, d: int, integrand,
                        rng_in_kernel: bool, accum_dtype=jnp.float32):
-    (rng_or_u_ref, cube_ref, ew_ref, *const_refs,
+    (rng_or_u_ref, cube_ref, table_ref, *const_refs,
      ms_ref, mc_ref, s1_ref, s2_ref) = refs
     if rng_in_kernel:
         kd_ref = rng_or_u_ref
@@ -324,9 +378,8 @@ def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
 
     lanes = jax.lax.broadcasted_iota(jnp.int32, (1, ninc), 1)   # (1, ninc)
 
-    # ---- pass 1: per-dimension transform.  One STACKED gather matvec per
-    # dimension: oh @ [edges_k; widths_k]^T picks (e_lo, dx) together — half
-    # the baseline's MXU ops / VMEM passes for the table lookups. ----
+    # ---- pass 1: per-dimension transform.  One gather matvec per
+    # dimension picks (e_lo, dx) together from the split table. ----
     x_cols = []
     ohs = []                                    # kept live for pass 2 reuse
     logjac = jnp.zeros((tile, 1), dtype)
@@ -336,12 +389,8 @@ def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
         yn = y_k * ninc
         iy_k = jnp.clip(yn.astype(jnp.int32), 0, ninc - 1)      # (tile, 1)
         frac = yn - iy_k.astype(dtype)
-        oh = (iy_k == lanes).astype(dtype)                      # (tile, ninc)
-        ed = jax.lax.dot_general(
-            oh, ew_ref[2 * k:2 * k + 2, :], (((1,), (1,)), ((), ())),
-            precision=_EXACT, preferred_element_type=dtype)  # (tile, 2)
-        e_lo = ed[:, 0:1]
-        dx = ed[:, 1:2]
+        oh = (iy_k == lanes).astype(jnp.bfloat16)               # (tile, ninc)
+        e_lo, dx = _gather_map(oh, table_ref, k)                # (tile, 1) x2
         x_cols.append(e_lo + frac * dx)
         ohs.append(oh)
         logjac = logjac + jnp.log(jnp.maximum(ninc * dx, _TINY))
@@ -355,7 +404,6 @@ def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
     fx = fx.reshape(tile, 1).astype(dtype)
     w = jnp.where(valid, jac * fx, jnp.zeros((), dtype))        # (tile, 1)
     w2 = w * w
-    cnt = valid.astype(dtype)
 
     @pl.when(i == 0)
     def _init():
@@ -365,19 +413,16 @@ def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
         s2_ref[...] = jnp.zeros_like(s2_ref)
 
     # ---- pass 2: map histogram.  REUSES the pass-1 one-hots (no second
-    # construction) and contracts [w2, cnt] in ONE stacked matmul per dim
-    # (the baseline runs two).  Products run in f32 on the MXU; the §15
-    # widening happens on the per-tile partial, just before the running
-    # sum into the (possibly f64) VMEM accumulator ref. ----
+    # construction) and contracts [w2 pieces, cnt] in ONE matmul per dim.
+    # Products are exact and summed in f32 on the MXU; the §15 widening
+    # happens on the per-tile partial, just before the running sum into the
+    # (possibly f64) VMEM accumulator ref. ----
     accum = jnp.dtype(accum_dtype)
-    w2cnt = jnp.concatenate([w2, cnt], axis=1)                  # (tile, 2)
+    w2cnt = _stack(split=(w2,), exact=(valid.astype(dtype),))   # (tile, 4)
     for k in range(d):
-        m_k = jax.lax.dot_general(
-            w2cnt, ohs[k], (((0,), (0,)), ((), ())),
-            precision=_EXACT, preferred_element_type=dtype)  # (2, ninc)
-        m_k = m_k.astype(accum)
-        ms_ref[k:k + 1, :] += m_k[0:1, :]
-        mc_ref[k:k + 1, :] += m_k[1:2, :]
+        ms_k, mc_k = _onehot_sums(w2cnt, ohs[k], 1)
+        ms_ref[k:k + 1, :] += ms_k.astype(accum)
+        mc_ref[k:k + 1, :] += mc_k.astype(accum)
 
     # ---- fused cube accumulation (P-V3 part 2) ----
     # Sorted ids advance by <= 1 per eval (every cube draws >= 2 evals), so
@@ -389,11 +434,8 @@ def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
     base = (cube_c[0, 0] // LANE) * LANE                        # scalar
     rel = jnp.clip(cube_c - base, 0, span - 1)                  # (tile, 1)
     win = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
-    ohc = (rel == win).astype(dtype)                            # (tile, span)
-    both = jnp.concatenate([w, w2], axis=1)                     # (tile, 2)
-    parts = jax.lax.dot_general(
-        both, ohc, (((0,), (0,)), ((), ())),
-        precision=_EXACT, preferred_element_type=dtype)         # (2, span)
+    ohc = (rel == win).astype(jnp.bfloat16)                     # (tile, span)
+    s1, s2 = _onehot_sums(_stack(split=(w, w2)), ohc, 2)       # (1, span) x2
     br = base // LANE
     # Same §15 boundary as the map histogram: the one-hot contraction stays
     # f32, each tile's partial is widened once before the grid-sequential +=
@@ -402,8 +444,8 @@ def _fill_fused_kernel(*refs, nstrat: int, n_cubes: int, ninc: int,
     # for reshaping a lane-major row vector into sublanes.
     for r in range(span // LANE):
         lanes_r = slice(r * LANE, (r + 1) * LANE)
-        s1_ref[pl.ds(br + r, 1), :] += parts[0:1, lanes_r].astype(accum)
-        s2_ref[pl.ds(br + r, 1), :] += parts[1:2, lanes_r].astype(accum)
+        s1_ref[pl.ds(br + r, 1), :] += s1[:, lanes_r].astype(accum)
+        s2_ref[pl.ds(br + r, 1), :] += s2[:, lanes_r].astype(accum)
 
 
 def vegas_fill_fused(key_bits, cube, edges_lo, widths, *, nstrat: int,
@@ -419,8 +461,9 @@ def vegas_fill_fused(key_bits, cube, edges_lo, widths, *, nstrat: int,
       accum_dtype: accumulator dtype (default f32).  Under the §15 widened
                 policy the four output buffers — and the VMEM accumulator
                 tiles behind them — are f64 while every product (transform,
-                integrand, one-hot matmuls) stays f32 for the MXU; each
-                tile's partial is widened once before the running ``+=``.
+                integrand) stays f32 and the one-hot matmuls sum in f32 on
+                the MXU; each tile's partial is widened once before the
+                running ``+=``.
       u:        optional (chunk, d) f32 uniforms.  ``None`` (the compiled-TPU
                 default) generates them IN-KERNEL from ``key_bits`` — zero
                 per-eval input traffic.  Passing the precomputed chunk block
@@ -442,9 +485,6 @@ def vegas_fill_fused(key_bits, cube, edges_lo, widths, *, nstrat: int,
     accum = jnp.dtype(accum_dtype) if accum_dtype is not None else jnp.float32
     rows = padded_cube_rows(n_cubes, tile)
     rng_in_kernel = u is None
-    # Interleave the two map tables (rows 2k / 2k+1 = edges_k / widths_k) so
-    # pass 1 picks both with a single stacked gather matvec per dimension.
-    ew = jnp.stack([edges_lo, widths], axis=1).reshape(2 * d, ninc)
     kig, flat_consts, const_specs = _const_transport(integrand, ig_consts)
 
     kernel = functools.partial(
@@ -460,7 +500,7 @@ def vegas_fill_fused(key_bits, cube, edges_lo, widths, *, nstrat: int,
         in_specs=[
             first_in[1],                                    # key bits | u
             pl.BlockSpec((tile, 1), lambda i: (i, 0)),      # cube ids
-            pl.BlockSpec((2 * d, ninc), lambda i: (0, 0)),  # edges/widths
+            pl.BlockSpec((6 * d, ninc), lambda i: (0, 0)),  # split map table
             *const_specs,                                   # integrand consts
         ],
         out_specs=[
@@ -477,4 +517,4 @@ def vegas_fill_fused(key_bits, cube, edges_lo, widths, *, nstrat: int,
         ],
         interpret=interpret,
         name="vegas_fill_fused",
-    )(first_in[0], cube, ew, *flat_consts)
+    )(first_in[0], cube, _split_table(edges_lo, widths), *flat_consts)

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.experimental import pallas as pl
+
 import repro.kernels as K
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
@@ -278,6 +280,134 @@ def test_fused_jaxpr_no_ncap_array_any_dtype():
 
     visit(closed.jaxpr)
     assert wide <= {jnp.dtype(jnp.int32)}, f"n_cap-sized array leaked: {wide}"
+
+
+# ---------------------------------------------------------------------------
+# One-hot contractions: one exact bf16 MXU pass (DESIGN.md D1/D2)
+# ---------------------------------------------------------------------------
+
+def _signed_binades(key, shape, lo_exp, hi_exp):
+    """f32 values with random signs, magnitudes spread log-uniformly over
+    10**lo_exp .. 10**hi_exp, and random mantissas."""
+    k1, k2 = jax.random.split(key)
+    mag = 10.0 ** jax.random.uniform(k1, shape, minval=lo_exp, maxval=hi_exp)
+    sign = jnp.where(jax.random.bernoulli(k2, 0.5, shape), -1.0, 1.0)
+    return (sign * mag).astype(jnp.float32)
+
+
+def test_split_gather_returns_table_bit_for_bit():
+    """The gather of the split (6d, ninc) table rebuilds every entry exactly:
+    edges of both signs over 60 decades, widths down to 1e-30, zeros, and
+    values one ulp under a power of two (every mantissa bit set)."""
+    d, ninc, n = 3, 256, 512
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(18), 3)
+    edges = _signed_binades(k1, (d, ninc), -30, 30)
+    widths = jnp.abs(_signed_binades(k2, (d, ninc), -30, 30))
+    powers = np.float32(2.0) ** np.arange(-20, 20, dtype=np.float32)
+    full = np.nextafter(powers, np.float32(0))
+    edges = edges.at[:, :8].set(0.0).at[:, 8:48].set(-full)
+    widths = widths.at[:, :8].set(1e-30).at[:, 8:16].set(0.0)
+    widths = widths.at[:, 16:56].set(full)
+    # every slot at least once, then random ones
+    idx = jnp.concatenate(
+        [jnp.tile(jnp.arange(ninc, dtype=jnp.int32)[:, None], (1, d)),
+         jax.random.randint(k3, (n - ninc, d), 0, ninc, jnp.int32)])
+
+    def body(idx_ref, table_ref, out_ref):
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, ninc), 1)
+        cols = []
+        for k in range(d):
+            oh = (idx_ref[:, k:k + 1] == lanes).astype(jnp.bfloat16)
+            cols += vk._gather_map(oh, table_ref, k)
+        out_ref[...] = jnp.concatenate(cols, axis=1)
+
+    out = pl.pallas_call(
+        body, out_shape=jax.ShapeDtypeStruct((n, 2 * d), jnp.float32),
+        interpret=True)(idx, vk._split_table(edges, widths))
+    out = np.asarray(out)
+    for k in range(d):
+        ik = np.asarray(idx[:, k])
+        for col, table in ((2 * k, edges), (2 * k + 1, widths)):
+            np.testing.assert_array_equal(
+                out[:, col].view(np.uint32),
+                np.asarray(table)[k, ik].view(np.uint32))
+
+
+def test_split_sums_match_f64_to_f32_rounding():
+    """The map histogram (w2 split, count exact) and the cube moments (w and
+    w2 split) through one bf16 pass each agree with an f64 sum of the same
+    f32 products to f32 rounding; the counts are exact.  A bf16 operand
+    (the contraction without the split) is off by ~2**-9 relative."""
+    n, slots = 512, 128
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(19), 3)
+    ids = jax.random.randint(k1, (n, 1), 0, slots, jnp.int32)
+    w = _signed_binades(k2, (n, 1), -15, 15)
+    cnt = jax.random.bernoulli(k3, 0.7, (n, 1)).astype(jnp.float32)
+
+    def body(ids_ref, w_ref, cnt_ref, out_ref):
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (1, slots), 1)
+        oh = (ids_ref[...] == lanes).astype(jnp.bfloat16)
+        w = w_ref[...]
+        w2 = w * w
+        s1, s2 = vk._onehot_sums(vk._stack(split=(w, w2)), oh, 2)
+        ms, mc = vk._onehot_sums(
+            vk._stack(split=(w2,), exact=(cnt_ref[...],)), oh, 1)
+        out_ref[...] = jnp.concatenate([s1, s2, ms, mc], axis=0)
+
+    got = np.asarray(pl.pallas_call(
+        body, out_shape=jax.ShapeDtypeStruct((4, slots), jnp.float32),
+        interpret=True)(ids, w, cnt)).astype(np.float64)
+    i = np.asarray(ids).ravel()
+    w32 = np.asarray(w).ravel()
+    w64, w2_64 = w32.astype(np.float64), (w32 * w32).astype(np.float64)
+    eps = float(np.finfo(np.float32).eps)
+    for row, vals in ((0, w64), (1, w2_64), (2, w2_64)):
+        want = np.bincount(i, vals, slots)
+        bound = 8 * eps * np.bincount(i, np.abs(vals), slots)
+        assert (np.abs(got[row] - want) <= bound).all(), row
+    np.testing.assert_array_equal(
+        got[3], np.bincount(i, np.asarray(cnt).ravel(), slots))
+
+
+def _dot_generals(jaxpr):
+    """Every dot_general eqn inside the pallas_call kernel bodies of
+    ``jaxpr``, recursively."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    found = []
+
+    def visit(p, in_kernel):
+        if isinstance(p, ClosedJaxpr):
+            return visit(p.jaxpr, in_kernel)
+        if isinstance(p, (list, tuple)):
+            return [visit(x, in_kernel) for x in p]
+        if not isinstance(p, Jaxpr):
+            return
+        for eqn in p.eqns:
+            if in_kernel and eqn.primitive.name == "dot_general":
+                found.append(eqn)
+            inner = in_kernel or eqn.primitive.name == "pallas_call"
+            for param in eqn.params.values():
+                visit(param, inner)
+
+    visit(jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_kernel_contractions_are_single_bf16_passes(fused):
+    """Every contraction in the kernel body takes bf16 operands at default
+    precision into f32: one MXU pass each, d gathers and d histograms (and
+    the cube window in the fused kernel).  A multi-pass f32 contraction
+    (Precision.HIGHEST, or f32 operands) fails here on the CPU."""
+    d = 2
+    closed, _, _ = _fill_jaxpr(fused=fused, d=d)
+    dots = _dot_generals(closed.jaxpr)
+    assert len(dots) == 2 * d + (1 if fused else 0), len(dots)
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [jnp.bfloat16] * 2
+        assert eqn.params["precision"] in (
+            None, (jax.lax.Precision.DEFAULT,) * 2), eqn.params["precision"]
+        assert eqn.params["preferred_element_type"] == jnp.float32
 
 
 # ---------------------------------------------------------------------------

@@ -14,10 +14,12 @@ Expected ordering differs by where the backend widens (§15):
 
 * ref widens the weights BEFORE the within-chunk sums, so
   f32 > Kahan > widened, and the widened error is exactly 0 here.
-* pallas-fused keeps products AND the per-tile one-hot matmul in f32 for
-  the MXU and widens the per-tile partial sums after — so Kahan and
-  widening both eliminate only the cross-chunk error and share the same
-  within-chunk f32 floor: f32 > Kahan ~= widened > 0.
+* pallas-fused keeps products AND the per-tile one-hot matmul's sums in
+  f32 and widens the per-tile partial sums after — so Kahan and widening
+  both eliminate only the cross-chunk error and share the same
+  within-chunk floor: f32 > Kahan ~= widened.  The matmul sums the exact
+  bf16 pieces of each weight (8 significand bits apiece, §7), so with
+  2^k equal weights a tile that floor is 0: Kahan == widened == 0.
 """
 
 import dataclasses
@@ -102,12 +104,13 @@ def test_error_ordering_fused_interpret(x64):
     # the within-chunk floor shared by Kahan and widening bit-identical.
     e = _planted_errors(fill_mod.fill_pallas, neval=1 << 17, chunk=128,
                         interpret=True, fused_cubes=True)
-    # Products and per-tile matmul stay f32 (§15): widening removes only the
-    # cross-chunk drift, exactly like Kahan — both beat plain f32, neither
-    # goes below the in-kernel f32 floor.
+    # Products and the per-tile matmul's sums stay f32 (§15): widening
+    # removes only the cross-chunk drift, exactly like Kahan — both beat
+    # plain f32.  Each tile sums 2^k copies of the bf16 pieces of c, which
+    # is exact, and so is their rebuild: the in-kernel floor is 0 here.
     assert e["f32"] > e["kahan"], e
     assert e["f32"] > e["wide"], e
-    assert e["wide"] > 0.0, e
+    assert e["wide"] == 0.0, e
     assert abs(e["kahan"] - e["wide"]) <= 1e-12 * max(e["kahan"], 1.0), e
 
 

@@ -84,6 +84,25 @@ def test_fused_fill_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("ig_fn,tile", [
+    (lambda: igs.make_gaussian(dim=4, mu=0.5, sigma=0.01), 256),
+    (lambda: igs.make_roos_arnold(dim=10), 128),
+], ids=["gaussian_d4", "roos_arnold_d10"])
+def test_fused_fill_compiles_for_v5e_at_autotuned_tile(one_chip, ig_fn, tile):
+    """The benchmark's shapes (1e6 evaluations, ``max_cubes`` 2^18, chunk
+    16384): the tile the VMEM model picks for bf16 one-hots fits Mosaic's
+    scoped VMEM."""
+    ig = ig_fn()
+    rc = VegasConfig(neval=1_000_000, ninc=NINC, chunk=CHUNK,
+                     max_cubes=2 ** 18).resolve(ig.dim)
+    assert kops.autotune_tile(
+        rc.chunk, ig.dim, rc.ninc, rc.n_cubes,
+        row_bytes=kops.eval_row_bytes(ig, ig.dim)) == tile
+    compiled = jax.jit(_compiled_fill(ig, rc)).lower(
+        *_fill_args(rc, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_vmapped_family_fill_compiles_for_v5e(one_chip):
     fam = make_gaussian_family(np.linspace(0.2, 0.8, 64))
     rc = VegasConfig(neval=NEVAL, ninc=NINC, chunk=CHUNK).resolve(fam.dim)
